@@ -4,7 +4,9 @@
 
 Prints ``name,us_per_call,derived`` CSV rows.  With ``--json`` the rows
 are also written as structured JSON (default path BENCH_conquer.json in
-the repo root) so perf PRs leave a machine-readable trajectory.
+the repo root) so perf PRs leave a machine-readable trajectory.  Exits
+non-zero when any suite raised (after recording it as a ``<suite>_ERROR``
+row) and when ``--only`` names no suite.
 """
 
 from __future__ import annotations
@@ -93,6 +95,8 @@ def main(argv=None) -> None:
 
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.mesh is not None and jax.device_count() < args.mesh:
         raise RuntimeError(
@@ -161,6 +165,11 @@ def main(argv=None) -> None:
         "tune": lambda: bench_tune.run(report, quick=args.quick),
     }
 
+    if args.only and args.only not in suites:
+        ap.error(f"--only {args.only!r} names no suite; choose from "
+                 f"{', '.join(suites)}")
+
+    failed = []
     for name, fn in suites.items():
         if args.only and name != args.only:
             continue
@@ -171,8 +180,9 @@ def main(argv=None) -> None:
         current_suite[0] = name
         try:
             fn()
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # record it, run the rest, exit non-zero
             report(f"{name}_ERROR", 0.0, repr(e))
+            failed.append(name)
         print(f"# {name} took {time.time() - t0:.1f}s", flush=True)
 
     print(f"# total rows: {len(rows)}")
@@ -195,6 +205,8 @@ def main(argv=None) -> None:
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"# wrote {os.path.abspath(args.json)}")
+    if failed:
+        sys.exit(f"# suites raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

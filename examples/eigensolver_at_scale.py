@@ -30,6 +30,8 @@ def main():
 
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     import scipy.linalg as sla
 

@@ -12,9 +12,11 @@ import scipy.linalg as sla
 from repro.core import (eigvalsh_tridiagonal, eigvalsh_tridiagonal_br,
                         eigvalsh_tridiagonal_range, make_family,
                         workspace_model, workspace_model_lazy)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # A symmetric tridiagonal from the paper's `uniform` family.
     n = 2048
     d, e = make_family("uniform", n)

@@ -9,12 +9,14 @@ lowers for the decode_32k / long_500k cells (here on reduced configs).
 import argparse
 
 from repro.launch.serve import main as serve_main
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     args = ap.parse_args()
+    enable_compile_cache()
     serve_main(["--arch", args.arch, "--smoke", "--batch", "4",
                 "--prompt-len", "32", "--gen", "16"])
 

@@ -54,6 +54,8 @@ def main(argv=None):
 
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.serve import EigensolverClient
 
     sizes = (48, 56, 64)          # one shared padded bucket: N = 64

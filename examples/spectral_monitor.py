@@ -32,6 +32,7 @@ from repro.optim.spectral_adapt import SpectralGovernor
 from repro.serve import EigensolverClient
 from repro.spectral import lanczos_tridiag_batch, make_hvp, slq_spectrum
 from repro.spectral.slq import _rademacher_like
+from repro.runtime.compile_cache import enable_compile_cache
 
 PROBE_STEPS = 8
 
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--probe-every", type=int, default=15)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("qwen3-0.6b")
     opt = adamw(lr=3e-3)

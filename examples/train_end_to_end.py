@@ -20,6 +20,7 @@ import argparse
 import tempfile
 
 from repro.launch.train import main as train_main
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
                          "spectral monitor, hard asserts")
     ap.add_argument("--steps", type=int, default=200)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         with tempfile.TemporaryDirectory() as ckpt:

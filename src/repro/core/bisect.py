@@ -443,13 +443,15 @@ def _certify_executor(d, e2, lam, nvalid, tol_factor):
 
 
 @functools.partial(jax.jit, static_argnames=("maxiter",))
-def _refine_executor(d, e2, x, lo, hi, xp, gp, tgt, live, tol, *, maxiter):
+def _refine_executor(d, e2, x, lo, hi, xp, gp, mode, tgt, live, tol, *,
+                     maxiter):
     """Bracket-guarded f64 polish of the compacted live lanes.
 
     d: (B, n); e2: (B, n-1); x, lo, hi: (B, k) iterates and count-verified
     brackets; xp, gp: previous (iterate, g) pair seeding the secant slope
     (xp == x flags "no history": the slope divides to non-finite and the
-    first trip falls back to Newton); tgt: (B, k) int32 target indices;
+    first trip falls back to Newton); mode: (B, k) int32 lane mode (see
+    below); tgt: (B, k) int32 target indices;
     live: (B, k); tol: (B, 1) certification tolerance.
 
     Each trip runs ONE fused count+derivative sweep over all lanes.  With
@@ -462,25 +464,36 @@ def _refine_executor(d, e2, x, lo, hi, xp, gp, tgt, live, tol, *, maxiter):
     superlinear convergence (measured: halves total polish iterations);
     candidates are accepted only when finite, strictly inside the
     count-updated bracket, and on a credibly positive slope, falling back
-    to Newton then to the bisection midpoint.  Convergence freezes a
-    lane's entire state (freeze-per-bracket: results never depend on how
-    long stragglers iterate, so refinement is deterministic across
-    live-set compactions).  Returns (x, lo, hi, xp, gp, live, iters).
+    to Newton then to the bisection midpoint.
+
+    A lane converges only on a count-verified bracket no wider than half
+    the tolerance.  A vanishing step means x sits on SOME eigenvalue --
+    inside a tight cluster possibly a neighbour of the target -- so the
+    lane (mode 0) probes half a tolerance to the bracket's other side
+    (mode 1).  A probe that closes the bracket converges; one that does
+    not shows x had locked onto a neighbour, and the lane bisects from
+    then on (mode 2), which the trip budget always completes.  Convergence
+    freezes a lane's entire state (freeze-per-bracket: results never
+    depend on how long stragglers iterate, so refinement is deterministic
+    across live-set compactions).  Returns (x, lo, hi, xp, gp, mode, live,
+    iters).
     """
     REFINE_EXECUTOR_TRACES.increment()
     pivmin = _pivot_floor(e2, d.dtype)
     tolf = 0.5 * tol     # freeze at half the certification tolerance
 
     def cond(state):
-        it, x, lo, hi, xp, gp, live, its = state
+        it, x, lo, hi, xp, gp, mode, live, its = state
         return (it < maxiter) & jnp.any(live)
 
     def body(state):
-        it, x, lo, hi, xp, gp, live, its = state
+        it, x, lo, hi, xp, gp, mode, live, its = state
         cnt, s = _count_and_newton(d, e2, x, pivmin)
         above = cnt > tgt                  # count(x) >= j+1: lam_j <= x
-        nhi = jnp.where(above & live, x, hi)
-        nlo = jnp.where(~above & live, x, lo)
+        # The count at x only ever tightens the bracket (an iterate may
+        # start outside it).
+        nhi = jnp.where(above & live, jnp.minimum(x, hi), hi)
+        nlo = jnp.where(~above & live, jnp.maximum(x, lo), lo)
         g = 1.0 / s
         cand_n = x - g
         slope = (g - gp) / (x - xp)
@@ -488,19 +501,31 @@ def _refine_executor(d, e2, x, lo, hi, xp, gp, tgt, live, tol, *, maxiter):
         ok_s = (jnp.isfinite(cand_s) & (cand_s > nlo) & (cand_s < nhi)
                 & (slope > 0.05))
         ok_n = jnp.isfinite(cand_n) & (cand_n > nlo) & (cand_n < nhi)
-        nx = jnp.where(ok_s, cand_s,
-                       jnp.where(ok_n, cand_n, 0.5 * (nlo + nhi)))
-        conv = (nhi - nlo <= tolf) | (jnp.abs(nx - x) <= 0.25 * tolf)
+        mid = 0.5 * (nlo + nhi)
+        cand = jnp.where(ok_s, cand_s, cand_n)
+        # Bisect unless the step at least halves the previous one (the
+        # rtsafe rule): next to a cluster of m eigenvalues, 1/s moves by
+        # about distance/m per trip and would crawl.
+        fast = (xp == x) | (2.0 * jnp.abs(cand - x) <= jnp.abs(x - xp))
+        nx = jnp.where((ok_s | ok_n) & fast, cand, mid)
+        conv = nhi - nlo <= tolf
+        small = jnp.abs(nx - x) <= 0.25 * tolf
+        probe = jnp.where(above, x - 0.5 * tolf, x + 0.5 * tolf)
+        nmode = jnp.where(mode == 1, 2, jnp.where(small & (mode == 0), 1,
+                                                  mode))
+        nx = jnp.where(nmode == 2, mid, jnp.where(nmode == 1, probe, nx))
+        nmode = jnp.where(live, nmode, mode)
         nxp = jnp.where(live, x, xp)
         ngp = jnp.where(live, g, gp)
         nx = jnp.where(live, nx, x)
         its = its + jnp.sum(live, dtype=jnp.int32)
-        return it + 1, nx, nlo, nhi, nxp, ngp, live & ~conv, its
+        return it + 1, nx, nlo, nhi, nxp, ngp, nmode, live & ~conv, its
 
-    state = (jnp.asarray(0, jnp.int32), x, lo, hi, xp, gp, live,
+    state = (jnp.asarray(0, jnp.int32), x, lo, hi, xp, gp, mode, live,
              jnp.asarray(0, jnp.int32))
-    _, x, lo, hi, xp, gp, live, its = jax.lax.while_loop(cond, body, state)
-    return x, lo, hi, xp, gp, live, its
+    _, x, lo, hi, xp, gp, mode, live, its = jax.lax.while_loop(cond, body,
+                                                               state)
+    return x, lo, hi, xp, gp, mode, live, its
 
 
 def _bucket(k: int) -> int:
@@ -529,6 +554,7 @@ def _refine_misses(d, e2, lamh, loh, hih, tol_dev, miss):
     B, n = miss.shape
     xph = lamh.copy()      # xp == x: no secant history yet
     gph = np.zeros_like(lamh)
+    modeh = np.zeros(lamh.shape, np.int32)
     idxs = [np.nonzero(miss[b])[0].astype(np.int32) for b in range(B)]
     iters = 0
     for _ in range(_REFINE_MAX_LAUNCHES):
@@ -542,17 +568,18 @@ def _refine_misses(d, e2, lamh, loh, hih, tol_dev, miss):
             gidx[b, :len(ix)] = ix
             live[b, :len(ix)] = True
         take = lambda a: jnp.asarray(np.take_along_axis(a, gidx, axis=1))
-        x1, lo1, hi1, xp1, gp1, live1, its = _refine_executor(
+        x1, lo1, hi1, xp1, gp1, mode1, live1, its = _refine_executor(
             d, e2, take(lamh), take(loh), take(hih), take(xph), take(gph),
-            jnp.asarray(gidx), jnp.asarray(live), tol_dev,
+            take(modeh), jnp.asarray(gidx), jnp.asarray(live), tol_dev,
             maxiter=_REFINE_TRIPS)
         iters += int(its)
         x1, lo1, hi1 = np.asarray(x1), np.asarray(lo1), np.asarray(hi1)
         xp1, gp1, live1 = np.asarray(xp1), np.asarray(gp1), np.asarray(live1)
+        mode1 = np.asarray(mode1)
         for b in range(B):
             ix = gidx[b, live[b]]
             for src, dst in ((x1, lamh), (lo1, loh), (hi1, hih),
-                             (xp1, xph), (gp1, gph)):
+                             (xp1, xph), (gp1, gph), (mode1, modeh)):
                 dst[b, ix] = src[b, live[b]]
             idxs[b] = gidx[b, live[b] & live1[b]]
     return iters

@@ -114,13 +114,15 @@ def _leaf_solve(d_adj, e_pad, leaf, track_local=None):
     eb = (e_pad[:, :N].reshape(B, nb, leaf)[:, :, : leaf - 1]
           if leaf > 1 else None)
 
-    ii = jnp.arange(leaf)
-    T = jnp.zeros((B, nb, leaf, leaf), d_adj.dtype)
-    T = T.at[:, :, ii, ii].set(db)
-    if leaf > 1:
+    if leaf == 1:                        # 1x1 blocks: no eigensolve
+        lam, Q = db, jnp.ones((B, nb, 1, 1), d_adj.dtype)
+    else:
+        ii = jnp.arange(leaf)
         j = jnp.arange(leaf - 1)
+        T = jnp.zeros((B, nb, leaf, leaf), d_adj.dtype)
+        T = T.at[:, :, ii, ii].set(db)
         T = T.at[:, :, j, j + 1].set(eb).at[:, :, j + 1, j].set(eb)
-    lam, Q = jnp.linalg.eigh(T)          # ascending
+        lam, Q = jnp.linalg.eigh(T)      # ascending
     selected = [Q[:, :, 0, :], Q[:, :, leaf - 1, :]]
     if track_local is not None:
         tl = jnp.asarray(track_local, jnp.int32)

@@ -181,6 +181,17 @@ def _resolve_shards(mesh, padded_n: int, leaf: int) -> int:
     return shards
 
 
+def _default_leaf(dtype_name: str, precision: str) -> int:
+    """Built-in leaf size.  A native float64 tree on TPU starts from 1x1
+    leaves: the TPU's float64 ``eigh`` (XLA's Jacobi expansion in emulated
+    f64) leaves leaf eigenvalues ~7e-8 off (measured on a v5e), while the
+    tree's own merges keep float64 accuracy."""
+    if (dtype_name == "float64" and precision == "native"
+            and _tune.pinned_backend() == "tpu"):
+        return 1
+    return LEAF_DEFAULT
+
+
 def _tuned_leaf(n: int, dtype_name: str, precision: str):
     """Tuning-cache leaf for a request size, as (leaf, came_from_cache).
 
@@ -191,7 +202,8 @@ def _tuned_leaf(n: int, dtype_name: str, precision: str):
     N0, _ = _br._tree_shape(n, LEAF_DEFAULT)
     tuned = _tune.lookup("solve", n=N0, dtype=dtype_name,
                          precision=precision)
-    return int(tuned.get("leaf", LEAF_DEFAULT)), "leaf" in tuned
+    default = _default_leaf(dtype_name, precision)
+    return int(tuned.get("leaf", default)), "leaf" in tuned
 
 
 def resolve_leaf(leaf, n: int, dtype, precision: str = "native") -> int:
@@ -450,7 +462,6 @@ def _executor_sharded(d_pad, e_pad, track, *, mesh, shards, compress_halo,
     static argument (cached Mesh objects in `_solver_mesh` keep it a
     stable cache key), so same-mesh traffic never retraces.
     """
-    from repro.compat import shard_map as _shard_map
     from repro.dist.sharding import SOLVER_AXIS
     EXECUTOR_TRACES.increment()
     body = functools.partial(
@@ -464,9 +475,9 @@ def _executor_sharded(d_pad, e_pad, track, *, mesh, shards, compress_halo,
     # Outputs are genuinely replicated: everything past the transition
     # all-gather is computed identically on every device (the replication
     # checker cannot prove that through ppermute/axis_index, hence off).
-    mapped = _shard_map(body, mesh=mesh,
-                        in_specs=(sliced, sliced, PartitionSpec()),
-                        out_specs=PartitionSpec(), check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh,
+                           in_specs=(sliced, sliced, PartitionSpec()),
+                           out_specs=PartitionSpec(), check_vma=False)
     return mapped(d_pad, e_pad, track)
 
 

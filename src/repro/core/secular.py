@@ -52,8 +52,13 @@ import jax.numpy as jnp
 # carries MAXIT = 30.  The model guess starts such roots on their own
 # magnitude, restoring quadratic convergence inside the budget (found
 # by the cross-method conformance sweep; pinned by its n = 17..25
-# Wilkinson points).
-DEFAULT_NITER = 16
+# Wilkinson points).  Glued Wilkinson spectra (copies of a W+ block
+# coupled by 1e-4, pole pairs ~1e-8 apart) still need up to ~30 steps on
+# some roots -- DLAED4's own MAXIT: at 16 the native f64 tree missed them
+# by ~4e-8 from n = 64 on (3e7 x the 64 eps ||T|| bar at n = 1024), at 24
+# by 969 x the bar at n = 512; at 32 glued Wilkinson sits inside the bar
+# at n = 256 through 4096.
+DEFAULT_NITER = 32
 
 # The f32-aware budget for single-precision trees (the mixed-precision
 # pipeline and explicit dtype=float32 solves).  The safeguarded iteration
@@ -63,7 +68,7 @@ DEFAULT_NITER = 16
 # (the floor, not the budget, binds), while each extra iteration still
 # pays a full streamed secular sweep.  10 keeps two safety steps over the
 # observed floor; the f64 rationale above (and its Wilkinson crawl
-# guard) does not shrink, so DEFAULT_NITER stays 16 for f64 routes.
+# guard) does not shrink, so DEFAULT_NITER stays higher for f64 routes.
 DEFAULT_NITER_F32 = 10
 
 
@@ -446,13 +451,19 @@ def boundary_rows_update(R, d, z, origin, tau, kprime, *, chunk: int = 128):
         y = jnp.where(active_i[None, :], z[None, :] / safe, 0.0)  # (C, K)
         nrm = jnp.sqrt(jnp.sum(y * y, axis=-1))
         nrm = jnp.where(nrm > 0.0, nrm, 1.0)
-        cols = (R @ y.T) / nrm[None, :]  # (r, C)
+        cols = _rows_dot(R, y.T) / nrm[None, :]  # (r, C)
         return cols
 
     cols = jax.lax.map(chunk_fn, idx)             # (nchunk, r, C)
     cols = jnp.moveaxis(cols, 1, 0).reshape(r, -1)[:, :K]
     active_j = (jnp.arange(K) < kprime)[None, :]
     return jnp.where(active_j, cols, R).astype(dtype)
+
+
+def _rows_dot(R, y):
+    """R @ y at full precision: the default f32 matmul on TPU is one bf16
+    pass, which leaves the propagated rows ~1e-2 off (measured on a v5e)."""
+    return jnp.matmul(R, y, precision=jax.lax.Precision.HIGHEST)
 
 
 def _postpass_tile(ic, d, z, d_org, tau, kprime, rho, use_zhat):
@@ -462,14 +473,12 @@ def _postpass_tile(ic, d, z, d_org, tau, kprime, rho, use_zhat):
     serves both reductions:
 
       * row-reduction over j -> Gu-Eisenstat weight zhat_i for the tile's
-        poles (DLAED3's ratio-product form: the full root range is resident
-        in the tile, so the numerator/denominator factors pair up as
-        interlaced ratios (lam_j - d_i)/(d_j - d_i) and zhat finalizes
-        inside the tile with plain products -- no log/exp.  Deflation
-        guarantees pole separation > tol, which bounds the partial
-        products; this is LAPACK's own unscaled formulation, and it is
-        what makes the fused pass decisively cheaper than the two-pass
-        log-space pipeline),
+        poles (DLAED3's ratio form: the full root range is resident in the
+        tile, so the numerator/denominator factors pair up as interlaced
+        ratios (lam_j - d_i)/(d_j - d_i) and zhat finalizes inside the
+        tile.  The ratios are summed in logs: their full product is O(1),
+        but partial products over- or underflow at large K, and on TPU
+        (emulated float64 included) that left non-finite weights),
       * the tile's poles' additive contribution to EVERY root column of the
         selected-row update, using ``delta = -lam_diff`` and the freshly
         reconstructed weights.
@@ -494,11 +503,13 @@ def _postpass_tile(ic, d, z, d_org, tau, kprime, rho, use_zhat):
     if use_zhat:
         pole_diff = d[None, :] - d_i[:, None]
         selfmask = jnp.arange(K)[None, :] == ic_safe[:, None]
-        ok = active_j & ~selfmask
+        ok = active_j & ~selfmask & (pole_diff != 0.0)
         ratio = jnp.where(ok, lam_diff / jnp.where(ok, pole_diff, 1.0), 1.0)
-        prod = jnp.prod(ratio, axis=-1)
+        tiny = jnp.finfo(dtype).tiny
+        log_abs = lambda x: jnp.log(jnp.maximum(jnp.abs(x), tiny))
         self_term = (d_org[ic_safe] - d_i) + tau[ic_safe]   # lam_i - d_i
-        z2hat = jnp.abs(prod * self_term) / rho
+        z2hat = jnp.exp(jnp.sum(log_abs(ratio), axis=-1)
+                        + log_abs(self_term)) / rho
         zhat_c = jnp.sign(z_i) * jnp.sqrt(z2hat)
         zhat_c = jnp.where(valid_i, zhat_c, z_i).astype(dtype)
         w = jnp.where(valid_i, zhat_c, 0.0)
@@ -539,8 +550,8 @@ def secular_postpass(R, d, z, origin, tau, kprime, rho, *,
       (zhat, rows): reconstructed weights (== z when use_zhat=False or
       deflated) and the updated selected rows, matching the two-pass
       ``zhat_reconstruct`` + ``boundary_rows_update`` composition to
-      rounding (the fused pass reconstructs weights in DLAED3's
-      ratio-product arithmetic, the two-pass form in log space).
+      rounding (the fused pass sums DLAED3's interlaced ratios in logs,
+      the two-pass form sums numerator and denominator logs apart).
     """
     r, K = R.shape
     dtype = R.dtype
@@ -552,7 +563,7 @@ def secular_postpass(R, d, z, origin, tau, kprime, rho, *,
         ic = jnp.arange(K, dtype=jnp.int32)
         zhat, y = _postpass_tile(ic, d, z, d_org, tau, kprime, rho,
                                  use_zhat)
-        cols = R @ y                                      # (r, K)
+        cols = _rows_dot(R, y)                            # (r, K)
         nrm2 = jnp.sum(y * y, axis=0)
     else:
         C = min(chunk, K)
@@ -564,7 +575,7 @@ def secular_postpass(R, d, z, origin, tau, kprime, rho, *,
             zhat_c, y = _postpass_tile(ic, d, z, d_org, tau, kprime,
                                        rho, use_zhat)
             Rc = jnp.take(R, jnp.minimum(ic, K - 1), axis=1)   # (r, C)
-            cols_acc = cols_acc + Rc @ y
+            cols_acc = cols_acc + _rows_dot(Rc, y)
             nrm2_acc = nrm2_acc + jnp.sum(y * y, axis=0)
             return (cols_acc, nrm2_acc), zhat_c
 

@@ -14,7 +14,9 @@
 
 ops.py dispatches between the Pallas kernels (TPU / interpret), the
 chunked XLA fallbacks, and the dense small-K path (size-adaptive level
-dispatch); ref.py holds deliberately-naive dense oracles.
+dispatch), by device backend and operand dtype; layout.py holds the
+row/column helpers the kernels share; ref.py holds deliberately-naive
+dense oracles.
 """
 
 from repro.kernels.ops import (

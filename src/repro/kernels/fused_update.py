@@ -18,6 +18,13 @@ every root column.  Column contributions and squared norms accumulate
 across the (sequential) TPU grid into VMEM-resident output blocks; the
 O(K) normalization happens on the final grid step.
 
+Layout (see ``kernels/layout.py``): every per-problem vector is a
+``(B, 1, K)`` view read with ``(1, 1, K)`` blocks.  A pole block's own
+poles are transposed onto the sublanes, so each (C, T) slab has poles on
+the sublanes and a root tile on the lanes; the row update is r
+multiply-and-sublane-sum passes on the VPU (r is 2 or 3), in the
+working precision.
+
 VMEM budget per step: O(K) vectors + the (C, T) root-tile slab; the dense
 (K, K) secular eigenvector block is never materialized.
 """
@@ -29,6 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.layout import (col_iota, col_to_row, fori, per_block,
+                                  per_problem, row_iota, row_to_col, where)
 
 DEFAULT_POLE_BLOCK = 128
 DEFAULT_ROOT_TILE = 1024
@@ -45,188 +55,95 @@ def _root_tile_for(Kp: int, root_tile: int) -> int:
 
 def _fused_kernel(R_ref, d_ref, z_ref, dorg_ref, tau_ref, rho_ref,
                   kprime_ref, zhat_ref, cols_ref, nrm2_ref, *,
-                  root_tile, use_zhat, batched=False):
-    # ``batched``: refs carry a leading length-1 problem-block dim and the
-    # grid is (B, pole_blocks); the grid iterates problems in the major
-    # axis, so each problem's accumulator init (first pole block) and
-    # normalization (last pole block) stay correctly sequenced.
+                  root_tile, use_zhat):
+    # Blocks: R (1, r, Kp) and d, z, d[origin], tau (1, 1, Kp) resident;
+    # rho, kprime (1, 1, 1); zhat (1, 1, C) per pole block; cols
+    # (1, r, Kp) and nrm2 (1, 1, Kp) accumulate across the pole blocks of
+    # one problem.  Grid = (B, pole_blocks): the grid iterates problems in
+    # the major axis, so each problem's accumulator init (first pole
+    # block) and normalization (last pole block) stay correctly sequenced.
     r, Kp = R_ref.shape[-2:]
     C = zhat_ref.shape[-1]
     T = _root_tile_for(Kp, root_tile)
     num_tiles = Kp // T
-    dtype = d_ref.dtype
+    rho = rho_ref[0]
+    kprime = kprime_ref[0]
+    i = pl.program_id(1)
+    own = pl.ds(pl.multiple_of(i * C, C), C)
 
-    if batched:
-        d = d_ref[0]
-        z = z_ref[0]
-        d_org = dorg_ref[0]
-        tau = tau_ref[0]
-        rho = rho_ref[0, 0]
-        kprime = kprime_ref[0, 0]
-        i = pl.program_id(1)
-        num_blocks = pl.num_programs(1)
-        read_cols = lambda: cols_ref[0]
-        write_cols = lambda v: cols_ref.__setitem__(0, v)
-        read_nrm2 = lambda: nrm2_ref[0]
-        write_nrm2 = lambda v: nrm2_ref.__setitem__(0, v)
-        R_full = R_ref[0]
-    else:
-        d = d_ref[...]
-        z = z_ref[...]
-        d_org = dorg_ref[...]
-        tau = tau_ref[...]
-        rho = rho_ref[0]
-        kprime = kprime_ref[0]
-        i = pl.program_id(0)
-        num_blocks = pl.num_programs(0)
-        read_cols = lambda: cols_ref[...]
-        write_cols = lambda v: cols_ref.__setitem__(..., v)
-        read_nrm2 = lambda: nrm2_ref[...]
-        write_nrm2 = lambda v: nrm2_ref.__setitem__(..., v)
-        R_full = R_ref[...]
+    def own_col(ref, k=0):
+        return row_to_col(ref[0, k:k + 1, own])
 
-    ic = i * C + jax.lax.iota(jnp.int32, C)
+    def tile_at(t):
+        return pl.ds(pl.multiple_of(t * T, T), T)
+
+    ic = i * C + col_iota(C)
     valid_i = ic < kprime            # active, non-padded poles only
-    d_i = d[ic]
-    z_i = z[ic]
+    d_i = own_col(d_ref)
+    z_i = own_col(z_ref)
 
     @pl.when(i == 0)
     def _init():
-        write_cols(jnp.zeros((r, Kp), dtype))
-        write_nrm2(jnp.zeros((Kp,), dtype))
+        cols_ref[...] = jnp.zeros(cols_ref.shape, cols_ref.dtype)
+        nrm2_ref[...] = jnp.zeros(nrm2_ref.shape, nrm2_ref.dtype)
 
     # ---- phase 1: zhat for this pole block (row reduction over roots) ---
-    # DLAED3 ratio-product form: numerator/denominator factors pair up as
-    # interlaced ratios (lam_j - d_i)/(d_j - d_i), so the reduction is a
-    # plain product -- no log/exp in the sweep.  Deflation guarantees pole
-    # separation > tol, bounding the partials (LAPACK's own unscaled form).
-    def tile(t, prod):
-        start = (t * T).astype(jnp.int32)
-        dt = jax.lax.dynamic_slice(d, (start,), (T,))
-        dot = jax.lax.dynamic_slice(d_org, (start,), (T,))
-        tt = jax.lax.dynamic_slice(tau, (start,), (T,))
-        jt = start + jax.lax.iota(jnp.int32, T)
-        jmask = (jt < kprime)[None, :]
-        lam_diff = (dot[None, :] - d_i[:, None]) + tt[None, :]   # (C, T)
-        pole_diff = dt[None, :] - d_i[:, None]
-        selfmask = jt[None, :] == ic[:, None]
-        ok = jmask & ~selfmask
-        ratio = jnp.where(ok, lam_diff / jnp.where(ok, pole_diff, 1.0), 1.0)
-        return prod * jnp.prod(ratio, axis=-1)
+    # DLAED3 ratio form: numerator/denominator factors pair up as
+    # interlaced ratios (lam_j - d_i)/(d_j - d_i), whose full product is
+    # O(1) -- but a partial product over a contiguous run of j (a tile)
+    # over- or underflows f32 at large K, so the product is summed in logs.
+    tiny = jnp.finfo(d_ref.dtype).tiny
+
+    def log_abs(x):
+        return jnp.log(jnp.maximum(jnp.abs(x), tiny))
+
+    def tile(t, lsum):
+        cols = tile_at(t)
+        jt = t * T + row_iota(T)
+        lam_diff = (dorg_ref[0, :, cols] - d_i) + tau_ref[0, :, cols]
+        pole_diff = d_ref[0, :, cols] - d_i                     # (C, T)
+        ok = (jt < kprime) & (jt != ic) & (pole_diff != 0.0)
+        ratio = where(ok, lam_diff / where(ok, pole_diff, 1.0), 1.0)
+        return lsum + jnp.sum(log_abs(ratio), axis=1, keepdims=True)
 
     if use_zhat:
-        prod = jax.lax.fori_loop(0, num_tiles, tile,
-                                 jnp.ones((C,), dtype))
-        self_term = (d_org[ic] - d_i) + tau[ic]            # lam_i - d_i
-        z2hat = jnp.abs(prod * self_term) / rho
+        lsum = fori(num_tiles, tile, jnp.zeros_like(d_i))
+        self_term = (own_col(dorg_ref) - d_i) + own_col(tau_ref)
+        z2hat = jnp.exp(lsum + log_abs(self_term)) / rho
         zhat_c = jnp.sign(z_i) * jnp.sqrt(z2hat)
-        zhat_c = jnp.where(valid_i, zhat_c, z_i).astype(dtype)
+        zhat_c = where(valid_i, zhat_c, z_i)
     else:
         zhat_c = z_i
-    if batched:
-        zhat_ref[0, :] = zhat_c
-    else:
-        zhat_ref[...] = zhat_c
-    w = jnp.where(valid_i, zhat_c, 0.0)
+    zhat_ref[0] = col_to_row(zhat_c)
+    w = where(valid_i, zhat_c, 0.0)
 
     # ---- phase 2: this block's contribution to every root column --------
     # zhat is still in VMEM; no HBM round-trip between the phases.
-    Rc = jax.lax.dynamic_slice(
-        R_full, (jnp.zeros((), jnp.int32), jnp.asarray(i * C, jnp.int32)),
-        (r, C))
+    R_i = [own_col(R_ref, k) for k in range(r)]
 
-    def tile2(t, _):
-        start = (t * T).astype(jnp.int32)
-        dot = jax.lax.dynamic_slice(d_org, (start,), (T,))
-        tt = jax.lax.dynamic_slice(tau, (start,), (T,))
-        delta = (d_i[:, None] - dot[None, :]) - tt[None, :]      # (C, T)
-        ok = valid_i[:, None] & (delta != 0.0)
-        y = jnp.where(ok, w[:, None] / jnp.where(ok, delta, 1.0), 0.0)
-        contrib = jax.lax.dot_general(
-            Rc, y, (((1,), (0,)), ((), ())),
-            preferred_element_type=dtype)                        # (r, T)
-        prev = jax.lax.dynamic_slice(
-            read_cols(), (jnp.zeros((), jnp.int32), start), (r, T))
-        write_cols(jax.lax.dynamic_update_slice(
-            read_cols(), prev + contrib,
-            (jnp.zeros((), jnp.int32), start)))
-        prevn = jax.lax.dynamic_slice(read_nrm2(), (start,), (T,))
-        write_nrm2(jax.lax.dynamic_update_slice(
-            read_nrm2(), prevn + jnp.sum(y * y, axis=0), (start,)))
-        return 0
+    def tile2(t, carry):
+        cols = tile_at(t)
+        delta = (d_i - dorg_ref[0, :, cols]) - tau_ref[0, :, cols]  # (C, T)
+        ok = valid_i & (delta != 0.0)
+        y = where(ok, w / where(ok, delta, 1.0), 0.0)
+        for k in range(r):
+            cols_ref[0, k:k + 1, cols] += jnp.sum(R_i[k] * y, axis=0,
+                                                  keepdims=True)
+        nrm2_ref[0, :, cols] += jnp.sum(y * y, axis=0, keepdims=True)
+        return carry
 
-    jax.lax.fori_loop(0, num_tiles, tile2, 0)
+    fori(num_tiles, tile2, None)
 
     # Final grid step for this problem: apply the normalization in-place.
-    @pl.when(i == num_blocks - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finalize():
-        nrm = jnp.sqrt(read_nrm2())
-        write_cols(read_cols() / jnp.where(nrm > 0.0, nrm, 1.0)[None, :])
-
-
-@functools.partial(jax.jit, static_argnames=("use_zhat", "pole_block",
-                                             "root_tile", "interpret"))
-def secular_postpass_pallas(R, d, z, origin, tau, kprime, rho, *,
-                            use_zhat: bool = True,
-                            pole_block: int = DEFAULT_POLE_BLOCK,
-                            root_tile: int = DEFAULT_ROOT_TILE,
-                            interpret: bool = False):
-    """Fused Pallas post-pass.  Contract of core.secular.secular_postpass.
-
-    Returns (zhat, rows).  Both the pole and root index spaces are padded
-    to the pole-block multiple; padded poles satisfy ic >= K >= kprime and
-    contribute nothing, padded root columns are sliced off.
-    """
-    r, K = R.shape
-    C = min(pole_block, K)
-    grid = ((K + C - 1) // C,)
-    Kp = grid[0] * C
-
-    d_org = d[jnp.minimum(origin, K - 1)]
-    if Kp != K:
-        pad = Kp - K
-        R_p = jnp.pad(R, ((0, 0), (0, pad)))
-        d_p = jnp.pad(d, (0, pad))
-        z_p = jnp.pad(z, (0, pad))
-        dorg_p = jnp.pad(d_org, (0, pad))
-        tau_p = jnp.pad(tau, (0, pad))
-    else:
-        R_p, d_p, z_p, dorg_p, tau_p = R, d, z, d_org, tau
-
-    rho_arr = jnp.asarray(rho, d.dtype).reshape(1)
-    kp_arr = jnp.asarray(kprime, jnp.int32).reshape(1)
-
-    kernel = functools.partial(_fused_kernel, root_tile=root_tile,
-                               use_zhat=use_zhat)
-    zhat, cols, nrm2 = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r, Kp), lambda i: (0, 0)),  # R resident
-            pl.BlockSpec((Kp,), lambda i: (0,)),      # d
-            pl.BlockSpec((Kp,), lambda i: (0,)),      # z
-            pl.BlockSpec((Kp,), lambda i: (0,)),      # d[origin]
-            pl.BlockSpec((Kp,), lambda i: (0,)),      # tau
-            pl.BlockSpec((1,), lambda i: (0,)),       # rho
-            pl.BlockSpec((1,), lambda i: (0,)),       # kprime
-        ],
-        out_specs=[
-            pl.BlockSpec((C,), lambda i: (i,)),       # zhat, per pole block
-            pl.BlockSpec((r, Kp), lambda i: (0, 0)),  # cols accumulator
-            pl.BlockSpec((Kp,), lambda i: (0,)),      # nrm2 accumulator
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Kp,), d.dtype),
-            jax.ShapeDtypeStruct((r, Kp), R.dtype),
-            jax.ShapeDtypeStruct((Kp,), d.dtype),
-        ],
-        interpret=interpret,
-    )(R_p, d_p, z_p, dorg_p, tau_p, rho_arr, kp_arr)
-
-    active = jnp.arange(K) < kprime
-    zhat = jnp.where(active, zhat[:K], z).astype(d.dtype)
-    rows = jnp.where(active[None, :], cols[:, :K], R).astype(R.dtype)
-    return zhat, rows
+        def fin(t, carry):
+            cols = tile_at(t)
+            nrm = jnp.sqrt(nrm2_ref[0, :, cols])
+            cols_ref[0, :, cols] = cols_ref[0, :, cols] / where(
+                nrm > 0.0, nrm, 1.0)
+            return carry
+        fori(num_tiles, fin, None)
 
 
 @functools.partial(jax.jit, static_argnames=("use_zhat", "pole_block",
@@ -241,59 +158,63 @@ def secular_postpass_pallas_batch(R, d, z, origin, tau, kprime, rho, *,
     R: (B, r, K); d, z, origin, tau: (B, K); kprime, rho: (B,).  Problems
     map to the major grid axis (their accumulator blocks are disjoint),
     pole blocks to the minor axis -- a whole batched merge level's
-    post-pass is ONE kernel launch.  Per-problem math is identical to
-    :func:`secular_postpass_pallas`.
+    post-pass is ONE kernel launch.  Contract of
+    core.secular.secular_postpass_batched.
 
-    Returns (zhat (B, K), rows (B, r, K)).
+    Returns (zhat (B, K), rows (B, r, K)).  Both the pole and root index
+    spaces are padded to the pole-block multiple; padded poles satisfy
+    ic >= K >= kprime and contribute nothing, padded root columns are
+    sliced off.
     """
     B, r, K = R.shape
     C = min(pole_block, K)
-    nblk = (K + C - 1) // C
-    grid = (B, nblk)
-    Kp = nblk * C
+    Kp = -(-K // C) * C
 
     d_org = jnp.take_along_axis(d, jnp.minimum(origin, K - 1), axis=1)
-    if Kp != K:
-        pad = Kp - K
-        R_p = jnp.pad(R, ((0, 0), (0, 0), (0, pad)))
-        d_p = jnp.pad(d, ((0, 0), (0, pad)))
-        z_p = jnp.pad(z, ((0, 0), (0, pad)))
-        dorg_p = jnp.pad(d_org, ((0, 0), (0, pad)))
-        tau_p = jnp.pad(tau, ((0, 0), (0, pad)))
-    else:
-        R_p, d_p, z_p, dorg_p, tau_p = R, d, z, d_org, tau
-
-    rho_arr = jnp.asarray(rho, d.dtype).reshape(B, 1)
-    kp_arr = jnp.asarray(kprime, jnp.int32).reshape(B, 1)
+    pad = ((0, 0), (0, Kp - K))
+    vec = [jnp.pad(v, pad)[:, None, :] for v in (d, z, d_org, tau)]
+    R_p = jnp.pad(R, ((0, 0), (0, 0), (0, Kp - K)))
+    rho_arr = jnp.asarray(rho, d.dtype).reshape(B, 1, 1)
+    kp_arr = jnp.asarray(kprime, jnp.int32).reshape(B, 1, 1)
 
     kernel = functools.partial(_fused_kernel, root_tile=root_tile,
-                               use_zhat=use_zhat, batched=True)
-    zhat, cols, nrm2 = pl.pallas_call(
+                               use_zhat=use_zhat)
+    row = per_problem((1, 1, Kp))
+    rows_spec = per_problem((1, r, Kp))
+    scalar = per_problem((1, 1, 1))
+    zhat, cols, _ = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, r, Kp), lambda b, i: (b, 0, 0)),  # R, resident
-            pl.BlockSpec((1, Kp), lambda b, i: (b, 0)),        # d
-            pl.BlockSpec((1, Kp), lambda b, i: (b, 0)),        # z
-            pl.BlockSpec((1, Kp), lambda b, i: (b, 0)),        # d[origin]
-            pl.BlockSpec((1, Kp), lambda b, i: (b, 0)),        # tau
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),         # rho
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),         # kprime
-        ],
+        grid=(B, Kp // C),
+        in_specs=[rows_spec, row, row, row, row, scalar, scalar],
         out_specs=[
-            pl.BlockSpec((1, C), lambda b, i: (b, i)),         # zhat
-            pl.BlockSpec((1, r, Kp), lambda b, i: (b, 0, 0)),  # cols acc
-            pl.BlockSpec((1, Kp), lambda b, i: (b, 0)),        # nrm2 acc
+            per_block((1, 1, C)),                              # zhat
+            rows_spec,                                         # cols acc
+            row,                                               # nrm2 acc
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Kp), d.dtype),
+            jax.ShapeDtypeStruct((B, 1, Kp), d.dtype),
             jax.ShapeDtypeStruct((B, r, Kp), R.dtype),
-            jax.ShapeDtypeStruct((B, Kp), d.dtype),
+            jax.ShapeDtypeStruct((B, 1, Kp), d.dtype),
         ],
         interpret=interpret,
-    )(R_p, d_p, z_p, dorg_p, tau_p, rho_arr, kp_arr)
+    )(R_p, *vec, rho_arr, kp_arr)
 
     active = jnp.arange(K)[None, :] < kprime[:, None]
-    zhat = jnp.where(active, zhat[:, :K], z).astype(d.dtype)
-    rows = jnp.where(active[:, None, :], cols[:, :, :K], R).astype(R.dtype)
+    zhat = where(active, zhat[:, 0, :K], z).astype(d.dtype)
+    rows = where(active[:, None, :], cols[:, :, :K], R).astype(R.dtype)
     return zhat, rows
+
+
+def secular_postpass_pallas(R, d, z, origin, tau, kprime, rho, *,
+                            use_zhat: bool = True,
+                            pole_block: int = DEFAULT_POLE_BLOCK,
+                            root_tile: int = DEFAULT_ROOT_TILE,
+                            interpret: bool = False):
+    """Single-problem view of :func:`secular_postpass_pallas_batch`
+    (contract of core.secular.secular_postpass)."""
+    zhat, rows = secular_postpass_pallas_batch(
+        R[None], d[None], z[None], origin[None], tau[None],
+        jnp.asarray(kprime, jnp.int32)[None],
+        jnp.asarray(rho, d.dtype)[None], use_zhat=use_zhat,
+        pole_block=pole_block, root_tile=root_tile, interpret=interpret)
+    return zhat[0], rows[0]

@@ -1,15 +1,29 @@
 """Backend-dispatching jit wrappers for the eigensolver hot-spot kernels.
 
 ``backend``:
-  * "xla"    -- chunked pure-JAX implementations (repro.core.secular);
-                default on CPU hosts.
-  * "pallas" -- Pallas kernels; compiled natively on TPU, `interpret=True`
-                elsewhere (Python-level execution of the kernel body, used
-                by the test suite to validate the TPU kernels on CPU).
-  * "auto"   -- "pallas" on TPU, "xla" otherwise.
+  * "xla"    -- chunked pure-JAX implementations (repro.core.secular,
+                repro.core.bisect); the default off TPU.
+  * "pallas" -- Pallas kernels; compiled by Mosaic on TPU, run with
+                ``interpret=True`` on every other backend (the test suite
+                validates the TPU kernels on CPU this way, in any dtype).
+  * "auto"   -- "pallas" on TPU for 32-bit floats, "xla" otherwise.
 
-Size-adaptive dispatch: every op takes ``dense=`` -- when True (small K,
-decided per merge-tree level by ``stream_threshold``) the op runs the
+The dtype rule.  Mosaic has no 64-bit scalars, so no Pallas kernel can
+take a float64 operand on a TPU.  Every dispatcher resolves the backend
+from its operand dtype (:func:`resolve_backend`): on a TPU, "auto" runs
+float32 work (plain f32 solves and the f32 tree of ``precision="mixed"``)
+through the kernels and float64 work through XLA.  The choice is a static,
+trace-time function of (device backend, dtype) -- never a try/except --
+and an explicit ``set_backend("pallas")`` with a float64 operand on a TPU
+raises instead of changing path.
+
+The legacy two-pass kernels (``boundary_rows_update``,
+``zhat_reconstruct``; superseded by the fused post-pass) keep 1-D blocks
+and gathers that Mosaic refuses, so "auto" never selects them: they run
+as Pallas only when asked for explicitly.
+
+Size-adaptive dispatch: the merge ops take ``dense=`` -- when True (small
+K, decided per merge-tree level by ``stream_threshold``) the op runs the
 dense vectorized XLA path regardless of backend.  Small merges are
 launch/loop-overhead-bound, not bandwidth-bound, and the chunked/streamed
 formulations serialize under vmap exactly where K is small and the level
@@ -51,10 +65,25 @@ def _device_backend() -> str:
     return _tune.pinned_backend()
 
 
-def resolve_backend(backend: str | None = None) -> str:
+def resolve_backend(dtype, backend: str | None = None, *,
+                    auto_pallas: bool = True) -> str:
+    """The backend one dispatch runs on, from its operand dtype.
+
+    ``backend`` overrides the process-wide :func:`set_backend` choice.
+    ``auto_pallas=False`` marks a kernel that "auto" never selects.
+    Raises ValueError for an explicit "pallas" with a 64-bit operand on a
+    TPU (Mosaic has no 64-bit scalars).
+    """
     b = backend or _BACKEND
+    on_tpu = _device_backend() == "tpu"
+    wide = jnp.dtype(dtype).itemsize > 4
     if b == "auto":
-        return "pallas" if _device_backend() == "tpu" else "xla"
+        return "pallas" if on_tpu and auto_pallas and not wide else "xla"
+    if b == "pallas" and on_tpu and wide:
+        raise ValueError(
+            f"backend='pallas' cannot run {jnp.dtype(dtype).name} operands "
+            f"on TPU: Mosaic has no 64-bit scalars.  Use backend='auto' "
+            f"(float64 goes through XLA) or a float32 solve.")
     return b
 
 
@@ -83,7 +112,7 @@ def secular_solve(d, z2, rho, kprime, *, niter: int | None = None,
     if dense:
         return _sec.secular_solve(d, z2, rho, kprime, niter=niter,
                                   dense=True)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return secular_solve_pallas(d, z2, rho, kprime, niter=niter,
                                     root_block=chunk, interpret=_interpret())
     return _sec.secular_solve(d, z2, rho, kprime, niter=niter, chunk=chunk)
@@ -96,7 +125,7 @@ def secular_postpass(R, d, z, origin, tau, kprime, rho, *,
     if dense:
         return _sec.secular_postpass(R, d, z, origin, tau, kprime, rho,
                                      use_zhat=use_zhat, dense=True)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return secular_postpass_pallas(R, d, z, origin, tau, kprime, rho,
                                        use_zhat=use_zhat, pole_block=chunk,
                                        interpret=_interpret())
@@ -117,7 +146,7 @@ def secular_solve_batched(d, z2, rho, kprime, *, niter: int | None = None,
     if dense:
         return _sec.secular_solve_batched(d, z2, rho, kprime, niter=niter,
                                           dense=True)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return secular_solve_pallas_batch(d, z2, rho, kprime, niter=niter,
                                           root_block=chunk,
                                           interpret=_interpret())
@@ -137,7 +166,7 @@ def secular_postpass_batched(R, d, z, origin, tau, kprime, rho, *,
         return _sec.secular_postpass_batched(R, d, z, origin, tau, kprime,
                                              rho, use_zhat=use_zhat,
                                              dense=True)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return secular_postpass_pallas_batch(R, d, z, origin, tau, kprime,
                                              rho, use_zhat=use_zhat,
                                              pole_block=chunk,
@@ -159,7 +188,7 @@ def secular_merge_resident(d, z, R, rho, kprime, *,
     region.  Callers gate on K <= resident_threshold.
     """
     niter = resolve_niter(niter, d.dtype)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return resident_merge_pallas(d, z, R, rho, kprime, niter=niter,
                                      use_zhat=use_zhat,
                                      interpret=_interpret())
@@ -179,7 +208,7 @@ def secular_merge_resident_batched(d, z, R, rho, kprime, *,
     (origin (B, K) int32, tau (B, K), zhat (B, K), rows (B, r, K)).
     """
     niter = resolve_niter(niter, d.dtype)
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return resident_merge_pallas_batch(d, z, R, rho, kprime,
                                            niter=niter, use_zhat=use_zhat,
                                            interpret=_interpret())
@@ -200,7 +229,7 @@ def sturm_count_batched(d, e2, shifts, pivmin, *,
     rows carrying all B x S pivot lanes.  Integer-exact across backends.
     """
     from repro.core import bisect as _bis  # deferred: core imports ops
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend) == "pallas":
         return sturm_count_pallas_batch(d, e2, shifts, pivmin,
                                         shift_block=shift_block,
                                         interpret=_interpret())
@@ -209,7 +238,7 @@ def sturm_count_batched(d, e2, shifts, pivmin, *,
 
 def boundary_rows_update(R, d, z, origin, tau, kprime, *, chunk: int = 256,
                          backend: str | None = None):
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend, auto_pallas=False) == "pallas":
         return boundary_rows_update_pallas(R, d, z, origin, tau, kprime,
                                            root_block=chunk,
                                            interpret=_interpret())
@@ -218,7 +247,7 @@ def boundary_rows_update(R, d, z, origin, tau, kprime, *, chunk: int = 256,
 
 def zhat_reconstruct(d, z, origin, tau, kprime, rho, *, chunk: int = 256,
                      backend: str | None = None):
-    if resolve_backend(backend) == "pallas":
+    if resolve_backend(d.dtype, backend, auto_pallas=False) == "pallas":
         return zhat_reconstruct_pallas(d, z, origin, tau, kprime, rho,
                                        pole_block=chunk,
                                        interpret=_interpret())

@@ -18,10 +18,17 @@ dense: K <= threshold guarantees the (K, K) delta tile fits VMEM
 size-adaptive dispatch enforces -- large-K levels keep the streamed
 two-launch path.
 
+Layout (see ``kernels/layout.py``): d, z are ``(1, K)`` rows from a
+``(B, 1, K)`` view; roots (and, in the post-pass, poles being weighted)
+sit on the sublanes as ``(K, 1)`` columns.  Every gather the iteration
+needs is a one-hot select over the ``(K, K)`` tile the kernel forms
+anyway, so it is exact and costs one sweep.
+
 Math is identical to ``core.secular.secular_merge_resident`` (the dense
-XLA composition): the DLAED4 middle-way iteration of
-``kernels/secular_roots.py`` followed by the ratio-product DLAED3 post-pass
-of ``kernels/fused_update.py``, specialized to the fully-resident case.
+XLA composition): the DLAED4 middle-way iteration shared with
+``kernels/secular_roots.py`` (``middle_way``) followed by the
+DLAED3 post-pass of ``kernels/fused_update.py``,
+specialized to the fully-resident case.
 """
 
 from __future__ import annotations
@@ -33,191 +40,128 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.secular import DEFAULT_NITER
+from repro.kernels.layout import (col_iota, col_to_row, gather_col,
+                                  per_problem, row_iota, where)
+from repro.kernels.secular_roots import middle_way
 
 
 def _resident_kernel(d_ref, z_ref, R_ref, rho_ref, kprime_ref,
                      origin_ref, tau_ref, zhat_ref, rows_ref, *,
                      niter, use_zhat):
+    # Blocks: d, z (1, 1, K); R (1, r, K); rho, kprime (1, 1, 1); the
+    # outputs mirror them.  Grid = (B,).
     K = d_ref.shape[-1]
     r = R_ref.shape[-2]
     dtype = d_ref.dtype
 
-    d = d_ref[0]
+    d = d_ref[0]                                   # (1, K) poles on lanes
     z = z_ref[0]
-    R = R_ref[0]
-    rho = rho_ref[0, 0]
-    kprime = kprime_ref[0, 0]
+    rho = rho_ref[0]
+    kprime = kprime_ref[0]
     z2 = z * z
 
-    idxK = jax.lax.iota(jnp.int32, K)
-    jc = idxK
+    jc = col_iota(K)                               # (K, 1) roots
+    lane = row_iota(K)
     active_root = jc < kprime
     is_last = jc == (kprime - 1)
-    active_pole = idxK < kprime
-    zw = jnp.where(active_pole, z2, 0.0)
-    sum_z2 = jnp.sum(zw)
-    span = rho * sum_z2
+    active_pole = lane < kprime
+    zw = where(active_pole, z2, 0.0)
+    span = rho * jnp.sum(zw, axis=1, keepdims=True)
 
     # ---- phase 1: dense safeguarded middle-way root solve ---------------
-    d_j = d
+    d_j = gather_col(d, jc)
     jnext = jnp.minimum(jc + 1, K - 1)
-    gap_hi = jnp.where(is_last, d_j + span, d[jnext])
+    d_next = gather_col(d, jnext)
+    gap_hi = where(is_last, d_j + span, d_next)
     mid_lam = 0.5 * (d_j + gap_hi)
 
-    def g_at(lam):
-        delta = d[None, :] - lam[:, None]                       # (K, K)
-        ok = active_pole[None, :] & (delta != 0.0)
-        return 1.0 + rho * jnp.sum(
-            jnp.where(ok, zw[None, :] / jnp.where(ok, delta, 1.0), 0.0),
-            axis=-1)
-
-    f_mid = g_at(mid_lam)
+    delta_mid = d - mid_lam                                      # (K, K)
+    ok_mid = active_pole & (delta_mid != 0.0)
+    f_mid = 1.0 + rho * jnp.sum(
+        where(ok_mid, zw / where(ok_mid, delta_mid, 1.0), 0.0),
+        axis=1, keepdims=True)
 
     use_left = (f_mid > 0.0) | is_last
-    origin = jnp.where(use_left, jc, jnext)
-    d_org = d[origin]
+    origin = where(use_left, jc, jnext)
+    d_org = where(use_left, d_j, d_next)
     tau_mid = mid_lam - d_org
 
-    lo = jnp.where(use_left, jnp.zeros_like(tau_mid), tau_mid)
-    hi = jnp.where(use_left,
-                   jnp.where(is_last & (f_mid <= 0.0), span, tau_mid),
-                   jnp.zeros_like(tau_mid))
-    lo = jnp.where(is_last & (f_mid <= 0.0), tau_mid, lo)
+    n_lo = where(is_last, jnp.maximum(jc - 1, 0), jc)
+    n_hi = where(is_last, jc, jnext)
+    side_lo = (lane <= n_lo) & active_pole
+    d_shift = d - d_org                                          # (K, K)
 
-    n_lo = jnp.where(is_last, jnp.maximum(jc - 1, 0), jc)
-    n_hi = jnp.where(is_last, jc, jnext)
-    p_lo = d[n_lo] - d_org
-    p_hi = d[n_hi] - d_org
-    side_lo = (idxK[None, :] <= n_lo[:, None]) & active_pole[None, :]
-
-    d_shift = d[None, :] - d_org[:, None]                       # (K, K)
-
-    # Pole-hugging guess (mirrors core.secular._solve_chunk): linearized
-    # origin-dominant model r0 + r0' tau - rho*z2_org/tau = 0, preferred
-    # over the value-matched quadratic when it lands farther from the
-    # origin pole -- kills the near-double-root geometric crawl.
-    mask_rest = (active_pole[None, :]
-                 & (idxK[None, :] != origin[:, None])
-                 & (d_shift != 0.0))
-    dsafe_h = jnp.where(mask_rest, d_shift, 1.0)
-    terms0 = jnp.where(mask_rest, z2[None, :] / dsafe_h, 0.0)
-    r0 = 1.0 + rho * jnp.sum(terms0, axis=-1)
-    rp0 = rho * jnp.sum(terms0 / dsafe_h, axis=-1)
-    c_org = rho * z2[origin]
-    sq_h = jnp.sqrt(jnp.maximum(r0 * r0 + 4.0 * rp0 * c_org, 0.0))
-    tau_m = jnp.where(use_left, -r0 + sq_h, -(r0 + sq_h)) \
-        / jnp.where(rp0 > 0.0, 2.0 * rp0, 1.0)
-    valid_m = (rp0 > 0.0) & jnp.isfinite(tau_m)
-
-    # Initial guess: value-matching 2-pole quadratic at tau_mid.
-    A_lo = rho * z2[n_lo]
-    A_hi = rho * z2[n_hi]
-    c0 = f_mid - A_lo / (p_lo - tau_mid) - A_hi / (p_hi - tau_mid)
-    qb = -(c0 * (p_lo + p_hi) + A_lo + A_hi)
-    qc = c0 * p_lo * p_hi + A_lo * p_hi + A_hi * p_lo
-    sq0 = jnp.sqrt(jnp.maximum(qb * qb - 4.0 * c0 * qc, 0.0))
-    qq0 = -0.5 * (qb + jnp.where(qb >= 0.0, 1.0, -1.0) * sq0)
-    g1 = jnp.where(c0 != 0.0, qq0 / jnp.where(c0 == 0.0, 1.0, c0), jnp.inf)
-    g2 = jnp.where(qq0 != 0.0, qc / jnp.where(qq0 == 0.0, 1.0, qq0), jnp.inf)
-    in1 = jnp.isfinite(g1) & (g1 > lo) & (g1 < hi)
-    in2 = jnp.isfinite(g2) & (g2 > lo) & (g2 < hi)
-    tau0 = jnp.where(in1, g1, jnp.where(in2, g2, 0.5 * (lo + hi)))
-    use_m = (valid_m & (tau_m > lo) & (tau_m < hi)
-             & (jnp.abs(tau_m) > jnp.abs(tau0)))
-    tau0 = jnp.where(use_m, tau_m, tau0)
-
-    tiny = jnp.finfo(dtype).tiny
+    mask_rest = active_pole & (lane != origin) & (d_shift != 0.0)
+    dsafe_h = where(mask_rest, d_shift, 1.0)
+    terms0 = where(mask_rest, z2 / dsafe_h, 0.0)
 
     def eval_g(tau):
-        delta = d_shift - tau[:, None]                          # (K, K)
-        ok = active_pole[None, :] & (delta != 0.0)
-        safe = jnp.where(ok, delta, 1.0)
-        terms = jnp.where(ok, zw[None, :] / safe, 0.0)
+        delta = d_shift - tau                                    # (K, K)
+        ok = active_pole & (delta != 0.0)
+        safe = where(ok, delta, 1.0)
+        terms = where(ok, zw / safe, 0.0)
         dterms = terms / safe
-        g = 1.0 + rho * jnp.sum(terms, axis=-1)
-        w_lo = rho * jnp.sum(jnp.where(side_lo, dterms, 0.0), axis=-1)
-        w_hi = rho * jnp.sum(jnp.where(side_lo, 0.0, dterms), axis=-1)
+        g = 1.0 + rho * jnp.sum(terms, axis=1, keepdims=True)
+        w_lo = rho * jnp.sum(where(side_lo, dterms, 0.0), axis=1,
+                             keepdims=True)
+        w_hi = rho * jnp.sum(where(side_lo, 0.0, dterms), axis=1,
+                             keepdims=True)
         return g, w_lo, w_hi
 
-    def body(_, state):
-        tau, lo, hi, best_tau, best_g = state
-        g, w_lo, w_hi = eval_g(tau)
-        gp = w_lo + w_hi
+    tau = middle_way(
+        eval_g, f_mid=f_mid, tau_mid=tau_mid, span=span, is_last=is_last,
+        use_left=use_left, p_lo=gather_col(d, n_lo) - d_org,
+        p_hi=gather_col(d, n_hi) - d_org,
+        r0=1.0 + rho * jnp.sum(terms0, axis=1, keepdims=True),
+        rp0=rho * jnp.sum(terms0 / dsafe_h, axis=1, keepdims=True),
+        c_org=rho * gather_col(z2, origin), A_lo=rho * gather_col(z2, n_lo),
+        A_hi=rho * gather_col(z2, n_hi), niter=niter)
 
-        better = jnp.abs(g) < best_g
-        best_tau = jnp.where(better, tau, best_tau)
-        best_g = jnp.where(better, jnp.abs(g), best_g)
+    single = active_root & (kprime == 1)
+    tau = where(single, rho * z2[:, 0:1], tau)
+    origin = where(single, jnp.zeros_like(origin), origin)
+    tau = where(active_root, tau, jnp.zeros_like(tau))
+    origin = where(active_root, origin, jc)
 
-        hi = jnp.where(g > 0.0, tau, hi)
-        lo = jnp.where(g <= 0.0, tau, lo)
-
-        D_lo = p_lo - tau
-        D_hi = p_hi - tau
-        Cc = g - D_lo * w_lo - D_hi * w_hi
-        Aa = (D_lo + D_hi) * g - D_lo * D_hi * gp
-        Bb = D_lo * D_hi * g
-        sq = jnp.sqrt(jnp.maximum(Aa * Aa - 4.0 * Bb * Cc, 0.0))
-        eta_neg = (Aa - sq) / jnp.where(Cc == 0.0, 1.0, 2.0 * Cc)
-        eta_pos = 2.0 * Bb / jnp.where(Aa + sq == 0.0, 1.0, Aa + sq)
-        eta = jnp.where(Aa <= 0.0, eta_neg, eta_pos)
-        eta_lin = Bb / jnp.where(Aa == 0.0, 1.0, Aa)
-        newton = -g / jnp.maximum(gp, tiny)
-        eta = jnp.where(Cc == 0.0, jnp.where(Aa != 0.0, eta_lin, newton), eta)
-        eta = jnp.where(g * eta >= 0.0, newton, eta)
-
-        cand = tau + eta
-        inb = jnp.isfinite(cand) & (cand > lo) & (cand < hi)
-        tau_next = jnp.where(inb, cand, 0.5 * (lo + hi))
-        tau_next = jnp.where(g == 0.0, tau, tau_next)
-        return tau_next, lo, hi, best_tau, best_g
-
-    big = jnp.full((K,), jnp.inf, dtype)
-    tau, lo, hi, best_tau, best_g = jax.lax.fori_loop(
-        0, niter, body, (tau0, lo, hi, tau0, big))
-    g_fin, _, _ = eval_g(tau)
-    tau = jnp.where(jnp.abs(g_fin) < best_g, tau, best_tau)
-
-    tau = jnp.where(active_root & (kprime == 1), rho * z2[0], tau)
-    origin = jnp.where(active_root & (kprime == 1), 0, origin)
-    tau = jnp.where(active_root, tau, jnp.zeros_like(tau))
-    origin = jnp.where(active_root, origin, jc)
-
-    origin_ref[0, :] = origin.astype(jnp.int32)
-    tau_ref[0, :] = tau.astype(dtype)
+    tau_row = col_to_row(tau)
+    origin_ref[0] = col_to_row(origin.astype(dtype)).astype(jnp.int32)
+    tau_ref[0] = tau_row
 
     # ---- phase 2: fused post-pass, (origin, tau) still on-chip ----------
-    # The d_org gather and the (K, K) delta tile are REUSED from the solve
-    # phase's register/VMEM state -- this is the HBM round-trip the
-    # two-launch pipeline pays and this kernel exists to remove.
-    d_org = d[origin]
-    lam_diff = (d_org[None, :] - d[:, None]) + tau[None, :]     # (K_i, K_j)
-    valid_i = active_pole                                       # poles axis
+    # (origin, tau) never round-trip HBM: this is the round-trip the
+    # two-launch pipeline pays and this kernel exists to remove.  Poles i
+    # on the sublanes, roots j on the lanes.
+    d_org = gather_col(d, origin)
+    lam_diff = (col_to_row(d_org) - d_j) + tau_row              # (K_i, K_j)
+    valid_i = active_root                                        # poles axis
+    z_i = gather_col(z, jc)
 
     if use_zhat:
-        pole_diff = d[None, :] - d[:, None]
-        selfmask = idxK[None, :] == idxK[:, None]
-        ok = active_pole[None, :] & ~selfmask
-        ratio = jnp.where(ok, lam_diff / jnp.where(ok, pole_diff, 1.0), 1.0)
-        prod = jnp.prod(ratio, axis=-1)
-        self_term = (d_org - d) + tau                           # lam_i - d_i
-        z2hat = jnp.abs(prod * self_term) / rho
-        zhat = jnp.sign(z) * jnp.sqrt(z2hat)
-        zhat = jnp.where(valid_i, zhat, z).astype(dtype)
-        w = jnp.where(valid_i, zhat, 0.0)
+        pole_diff = d - d_j
+        # Ratio product summed in logs (see kernels/fused_update.py).
+        ok = active_pole & (lane != jc) & (pole_diff != 0.0)
+        ratio = where(ok, lam_diff / where(ok, pole_diff, 1.0), 1.0)
+        self_term = (d_org - d_j) + tau                          # lam_i - d_i
+        tiny = jnp.finfo(dtype).tiny
+        log_abs = lambda x: jnp.log(jnp.maximum(jnp.abs(x), tiny))
+        z2hat = jnp.exp(jnp.sum(log_abs(ratio), axis=1, keepdims=True)
+                        + log_abs(self_term)) / rho
+        zhat = jnp.sign(z_i) * jnp.sqrt(z2hat)
+        zhat = where(valid_i, zhat, z_i)
     else:
-        zhat = z
-        w = jnp.where(valid_i, z, 0.0)
-    zhat_ref[0, :] = zhat
+        zhat = z_i
+    zhat_ref[0] = col_to_row(zhat)
+    w = where(valid_i, zhat, 0.0)
 
     delta = -lam_diff                         # (d_i - d_org_j) - tau_j
-    ok = valid_i[:, None] & (delta != 0.0)
-    y = jnp.where(ok, w[:, None] / jnp.where(ok, delta, 1.0), 0.0)  # (K, K)
-    cols = jax.lax.dot_general(
-        R, y, (((1,), (0,)), ((), ())), preferred_element_type=dtype)
-    nrm = jnp.sqrt(jnp.sum(y * y, axis=0))
-    cols = cols / jnp.where(nrm > 0.0, nrm, 1.0)[None, :]
-    active_j = active_pole[None, :]
-    rows_ref[0, :, :] = jnp.where(active_j, cols, R).astype(R.dtype)
+    ok = valid_i & (delta != 0.0)
+    y = where(ok, w / where(ok, delta, 1.0), 0.0)       # (K, K)
+    nrm = jnp.sqrt(jnp.sum(y * y, axis=0, keepdims=True))
+    nrm = where(nrm > 0.0, nrm, 1.0)
+    for k in range(r):
+        R_k = R_ref[0, k:k + 1, :]
+        col = jnp.sum(gather_col(R_k, jc) * y, axis=0, keepdims=True)
+        rows_ref[0, k:k + 1, :] = where(active_pole, col / nrm, R_k)
 
 
 @functools.partial(jax.jit, static_argnames=("niter", "use_zhat",
@@ -236,36 +180,28 @@ def resident_merge_pallas_batch(d, z, R, rho, kprime, *, niter: int = DEFAULT_NI
     Returns (origin (B, K) int32, tau (B, K), zhat (B, K), rows (B, r, K)).
     """
     B, r, K = R.shape
-    rho_arr = jnp.asarray(rho, d.dtype).reshape(B, 1)
-    kp_arr = jnp.asarray(kprime, jnp.int32).reshape(B, 1)
+    rho_arr = jnp.asarray(rho, d.dtype).reshape(B, 1, 1)
+    kp_arr = jnp.asarray(kprime, jnp.int32).reshape(B, 1, 1)
 
     kernel = functools.partial(_resident_kernel, niter=niter,
                                use_zhat=use_zhat)
+    row = per_problem((1, 1, K))
+    rows_spec = per_problem((1, r, K))
+    scalar = per_problem((1, 1, 1))
     origin, tau, zhat, rows = pl.pallas_call(
         kernel,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, K), lambda b: (b, 0)),      # d, per problem
-            pl.BlockSpec((1, K), lambda b: (b, 0)),      # z
-            pl.BlockSpec((1, r, K), lambda b: (b, 0, 0)),  # R
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),      # rho
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),      # kprime
-        ],
-        out_specs=[
-            pl.BlockSpec((1, K), lambda b: (b, 0)),
-            pl.BlockSpec((1, K), lambda b: (b, 0)),
-            pl.BlockSpec((1, K), lambda b: (b, 0)),
-            pl.BlockSpec((1, r, K), lambda b: (b, 0, 0)),
-        ],
+        in_specs=[row, row, rows_spec, scalar, scalar],
+        out_specs=[row, row, row, rows_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, K), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), d.dtype),
-            jax.ShapeDtypeStruct((B, K), d.dtype),
+            jax.ShapeDtypeStruct((B, 1, K), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, K), d.dtype),
+            jax.ShapeDtypeStruct((B, 1, K), d.dtype),
             jax.ShapeDtypeStruct((B, r, K), R.dtype),
         ],
         interpret=interpret,
-    )(d, z, R, rho_arr, kp_arr)
-    return origin, tau, zhat, rows
+    )(d[:, None, :], z[:, None, :], R, rho_arr, kp_arr)
+    return origin[:, 0], tau[:, 0], zhat[:, 0], rows
 
 
 def resident_merge_pallas(d, z, R, rho, kprime, *, niter: int = DEFAULT_NITER,

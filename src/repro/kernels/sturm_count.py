@@ -7,21 +7,24 @@ shifts and across problems.  Mapping:
 
   work axis                     TPU / Pallas
   ----------------------------  ------------------------------------------
-  problems (B)                  grid axis 0 -- each step owns one
-                                problem's (n,) d / e^2 rows, VMEM-resident
-  probe shifts (S)              grid axis 1 in SHIFT_BLOCK-wide lanes; the
-                                pivot recurrence runs once per block with
-                                every lane carrying its own shift's pivot
-  matrix rows (n)               sequential fori over the resident vectors
+  problems (B)                  grid axis 0
+  probe shifts (S)              grid axis 1 in blocks of SHIFT_BLOCK shifts,
+                                laid out (rows, 128 lanes) so a block of
+                                1024 shifts is one (8, 128) vector; every
+                                element carries its own shift's pivot
+  matrix rows (n)               sequential fori; d and e^2 stay in HBM and
+                                are copied ROW_CHUNK rows at a time into
+                                SMEM, where each row is a scalar read
                                 (the recurrence is a linear chain -- this
                                 is the irreducible dependence)
 
-VMEM budget per grid step: 2n + O(SHIFT_BLOCK) floats.  The count uses
-LAPACK DSTEBZ's guarded negcount convention (pivots within ``pivmin`` of
-zero are counted as negative), identical to the XLA scan in
-``core.bisect.sturm_count_xla`` -- ref.py / tests assert exact integer
-agreement across shapes, dtypes and degenerate (zero off-diagonal)
-inputs.
+The recurrence runs over the shifted squared off-diagonals
+``e2s = [0, e^2]`` from a unit starting pivot, so row 0 is the same
+update as every other row.  The count uses LAPACK DSTEBZ's guarded
+negcount convention (pivots within ``pivmin`` of zero are counted as
+negative), identical to the XLA scan in ``core.bisect.sturm_count_xla``
+-- ref.py / tests assert exact integer agreement across shapes, dtypes
+and degenerate (zero off-diagonal) inputs.
 """
 
 from __future__ import annotations
@@ -31,32 +34,48 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_SHIFT_BLOCK = 128
+from repro.kernels.layout import ZERO, fori, per_problem, round_up, where
+
+DEFAULT_SHIFT_BLOCK = 1024
+ROW_CHUNK = 1024
+_LANES = 128
 
 
-def _sturm_kernel(d_ref, e2_ref, shifts_ref, pivmin_ref, count_ref):
-    # Blocks: d (1, n), e2 (1, n) (last entry is a zero pad -- the
-    # recurrence reads e2[i-1] for i in [1, n)), shifts (1, C),
-    # pivmin (1, 1); grid = (B, shift_blocks).
-    d = d_ref[0]
-    e2 = e2_ref[0]
+def _sturm_kernel(d_hbm, e2_hbm, shifts_ref, pivmin_ref, count_ref,
+                  d_buf, e2_buf, d_sem, e2_sem, *, n, row_stride):
+    # d_hbm, e2_hbm: flat (B * row_stride,) in HBM; shifts (1, R, L);
+    # pivmin (1, 1, 1); d_buf, e2_buf: (chunk,) SMEM; grid = (B, blocks).
+    chunk = d_buf.shape[0]
+    base = pl.program_id(0) * row_stride
     sig = shifts_ref[0]
-    pivmin = pivmin_ref[0, 0]
-    n = d.shape[0]
+    pivmin = pivmin_ref[0]
 
-    q = d[0] - sig
-    q = jnp.where(jnp.abs(q) < pivmin, -pivmin, q)
-    cnt = (q <= 0.0).astype(jnp.int32)
+    def rows(start, count, carry):
+        src = pl.ds(pl.multiple_of(base + start, chunk), chunk)
+        copies = [pltpu.make_async_copy(hbm.at[src], buf, sem)
+                  for hbm, buf, sem in ((d_hbm, d_buf, d_sem),
+                                        (e2_hbm, e2_buf, e2_sem))]
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
 
-    def body(i, carry):
-        q, cnt = carry
-        q = (d[i] - sig) - e2[i - 1] / q
-        q = jnp.where(jnp.abs(q) < pivmin, -pivmin, q)
-        return q, cnt + (q <= 0.0).astype(jnp.int32)
+        def row(j, carry):
+            q, cnt = carry
+            q = (d_buf[j] - sig) - e2_buf[j] / q
+            q = where(jnp.abs(q) < pivmin, -pivmin, q)
+            return q, cnt + (q <= 0.0).astype(jnp.int32)
+        return fori(count, row, carry)
 
-    q, cnt = jax.lax.fori_loop(1, n, body, (q, cnt))
-    count_ref[0, :] = cnt
+    carry = (jnp.ones(sig.shape, sig.dtype), jnp.zeros(sig.shape, jnp.int32))
+    full, tail = divmod(n, chunk)
+    carry = fori(
+        full, lambda c, carry: rows(c * chunk, chunk, carry), carry)
+    if tail:
+        carry = rows(full * chunk, tail, carry)
+    count_ref[0] = carry[1]
 
 
 @functools.partial(jax.jit, static_argnames=("shift_block", "interpret"))
@@ -72,29 +91,40 @@ def sturm_count_pallas_batch(d, e2, shifts, pivmin, *,
     """
     B, n = d.shape
     S = shifts.shape[1]
-    C = min(shift_block, S)
-    nblk = (S + C - 1) // C
-    Sp = nblk * C
+    L = min(shift_block, _LANES)
+    if shift_block % L:
+        raise ValueError(f"shift_block must be <= {_LANES} or a multiple "
+                         f"of it, got {shift_block}")
+    rows_total = -(-S // L)
+    R = min(shift_block // L, rows_total)
+    nblk = -(-rows_total // R)
+    Sp = nblk * R * L
     if Sp != S:
         # Pad lanes with the last shift: duplicated counts, sliced away.
         shifts = jnp.concatenate(
             [shifts, jnp.broadcast_to(shifts[:, -1:], (B, Sp - S))], axis=1)
 
-    # Uniform (B, n) e2 layout; the pad column is never read (i <= n-1).
-    e2p = jnp.zeros((B, n), d.dtype).at[:, : max(n - 1, 0)].set(e2)
-    pivmin = jnp.asarray(pivmin, d.dtype).reshape(B, 1)
+    # Whole ROW_CHUNKs per problem: Mosaic tiles a flat HBM array in
+    # 1024-element units, so every copy must start on one.
+    chunk = ROW_CHUNK
+    stride = round_up(n, chunk)
+    e2s = jnp.zeros((B, stride), d.dtype).at[:, 1:n].set(e2)
+    d_flat = jnp.pad(d, ((0, 0), (0, stride - n))).reshape(-1)
+    pivmin = jnp.asarray(pivmin, d.dtype).reshape(B, 1, 1)
 
+    block = pl.BlockSpec((1, R, L), lambda b, i: (b, i, ZERO))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     counts = pl.pallas_call(
-        _sturm_kernel,
+        functools.partial(_sturm_kernel, n=n, row_stride=stride),
         grid=(B, nblk),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda b, i: (b, 0)),   # d, per problem
-            pl.BlockSpec((1, n), lambda b, i: (b, 0)),   # e^2
-            pl.BlockSpec((1, C), lambda b, i: (b, i)),   # shift lanes
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),   # pivot floor
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, Sp), jnp.int32),
+        in_specs=[hbm, hbm, block,
+                  per_problem((1, 1, 1))],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((B, nblk * R, L), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((chunk,), d.dtype),
+                        pltpu.SMEM((chunk,), d.dtype),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(d, e2p, shifts, pivmin)
-    return counts[:, :S]
+    )(d_flat, e2s.reshape(-1), shifts.reshape(B, nblk * R, L), pivmin)
+    return counts.reshape(B, Sp)[:, :S]

@@ -8,7 +8,14 @@ a single device.
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis Auto (sharding by annotation)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -67,8 +74,6 @@ def make_solver_mesh(shards: int):
     first jax init silently does nothing, so a shortfall here raises
     with the fix spelled out rather than falling back to one device.
     """
-    import jax
-
     from repro.dist.sharding import SOLVER_AXIS
 
     if shards < 1:

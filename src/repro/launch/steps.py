@@ -97,12 +97,12 @@ def make_train_step_compressed(cfg: ModelConfig, optimizer, mesh, *,
         batch_specs = jax.tree.map(lambda _: P("pod"), batch)
         rep = jax.tree.map(lambda _: P(), params)
         err_specs = jax.tree.map(lambda _: P(), err_state)
-        from repro.compat import shard_map
-        loss, ce, grads, new_err = shard_map(
+        loss, ce, grads, new_err = jax.shard_map(
             pod_local, mesh=mesh,
             in_specs=(rep, batch_specs, err_specs),
             out_specs=(P(), P(), rep, err_specs),
-            axis_names={"pod"}, check_vma=False)(params, batch, err_state)
+            axis_names=frozenset({"pod"}),
+            check_vma=False)(params, batch, err_state)
 
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(g.astype(jnp.float32)))

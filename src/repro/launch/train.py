@@ -43,6 +43,7 @@ from repro.models import transformer as tf
 from repro.optim.optimizers import get_optimizer
 from repro.optim.spectral_adapt import SpectralGovernor
 from repro.runtime import StragglerMonitor, Watchdog
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.spectral import lanczos_tridiag_batch, make_hvp
 from repro.spectral.slq import _rademacher_like, edges_from_tridiag
 
@@ -219,4 +220,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -390,7 +390,6 @@ def test_sharding_prunes_nondivisible():
 
 
 def test_int8_compression_error_feedback():
-    from repro.compat import shard_map
     from repro.dist.compression import (CompressionState,
                                         compressed_cross_pod_mean,
                                         init_compression_state)
@@ -403,7 +402,7 @@ def test_int8_compression_error_feedback():
     def f(g, err):
         return compressed_cross_pod_mean(g, CompressionState(err), "pod")
 
-    out, new_state = shard_map(
+    out, new_state = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False)(grads, state.error)
     # single-pod mean == dequantized self; error feedback bounds the bias
