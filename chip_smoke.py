@@ -14,22 +14,30 @@ every eigenvalue of the certified routes is also count-certified by the
 solver itself.
 
 
+  arith    -- float64 arithmetic on the device against numpy: the largest
+              relative error of add, mul, div, sqrt and log in units of
+              eps_f64, and the smallest power of two the device keeps.
+              A TPU emulates float64 with fewer mantissa bits and
+              float32's exponent range, which is why the default float64
+              solve on a TPU takes the certified mixed route;
   large    -- ``repro.core.eigvalsh_tridiagonal`` on one n = 65536 problem
               (the paper's case: its f32 eigenvector matrix alone would be
               17.2 GB, more than a v5e's 16 GB of HBM), for the weakly
               deflating ``uniform`` family and the strongly deflating
-              glued Wilkinson family, with ``precision="mixed",
-              certify=True`` (the f32 tree through the Pallas kernels, then
-              the f64 Sturm certificate), and the same families in native
-              float64 (the XLA path) at n = NATIVE_N;
+              glued Wilkinson family: the default float64 call (on a TPU
+              ``precision="auto"`` resolves to the mixed route: the f32
+              tree through the Pallas kernels, then float64 Sturm
+              certification and polish through XLA), cold and warm, and
+              ``precision="mixed", certify=True`` with no escalation down
+              the degradation ladder allowed;
   serve    -- ``repro.serve.EigensolverClient``: a burst of 256 independent
               n = 96 SLQ-shaped problems through ``solve_async``, then a
               top-32 ``solve_range`` slice of the n = 65536 problem in
               float64 (Sturm counts through XLA) and in float32 (the Pallas
               ``sturm_count`` kernel), checked at float32's eps.
 
-``--chips 4`` runs only the sharded path: one n = SHARDED_N mixed-precision
-certified solve with ``mesh=4`` (what ``mesh="auto"`` picks on a four-chip
+``--chips 4`` runs only the sharded path: one n = SHARDED_N default
+float64 solve with ``mesh=4`` (what ``mesh="auto"`` picks on a four-chip
 host for padded n >= 16384), checked against scipy, and its largest
 difference from the same solve with ``mesh=1``.
 
@@ -68,11 +76,10 @@ from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 from repro.serve import EigensolverClient  # noqa: E402
 
 LARGE_N = 65536
-NATIVE_N = 1024
 FAMILIES = ("uniform", "glued_wilkinson")
 SLQ_N, SLQ_BATCH = 96, 256
 TOP_K = 32
-SHARDED_N = 16384
+SHARDED_N = 262144
 BAR_ULPS = 64
 REF_FULL_N = 4096
 REF_WINDOW = 256
@@ -201,51 +208,80 @@ def executable_stats(key) -> dict:
             "code_bytes": mem.generated_code_size_in_bytes}
 
 
+def arith_phase(seed: int) -> None:
+    """float64 arithmetic on the device, in eps_f64 units (informative:
+    the bar is checked on the solves)."""
+    rng = np.random.default_rng(seed)
+    a = rng.uniform(0.5, 2.0, 1 << 16) * 2.0 ** rng.integers(-20, 20, 1 << 16)
+    b = rng.uniform(0.5, 2.0, 1 << 16) * 2.0 ** rng.integers(-20, 20, 1 << 16)
+    ops_ = {"add": (jnp.add, np.add), "mul": (jnp.multiply, np.multiply),
+            "div": (jnp.divide, np.divide),
+            "sqrt": (lambda x, _: jnp.sqrt(x), lambda x, _: np.sqrt(x)),
+            "log": (lambda x, _: jnp.log(x), lambda x, _: np.log(x))}
+    ad, bd = jnp.asarray(a), jnp.asarray(b)
+    err = {}
+    for name, (fj, fn) in ops_.items():
+        got = np.asarray(jax.jit(fj)(ad, bd))
+        want = fn(a, b)
+        err[name] = float(np.max(np.abs(got - want) / np.abs(want))
+                          / np.finfo(np.float64).eps)
+    k = np.arange(100, 1100)
+    kept = np.asarray(jax.jit(jnp.multiply)(jnp.ones(len(k)),
+                                            jnp.asarray(2.0 ** -k)))
+    say("arith", dtype="float64", max_rel_err_eps64=err,
+        smallest_kept_power_of_two=int(-k[kept > 0].max()))
+
+
 def large_phase(seed: int) -> None:
-    """Both families share one route per precision: the first family's
-    call pays the route's compile and is repeated warm; the second
-    family's single call reports its own (small) compile seconds."""
-    for precision, n in (("mixed", LARGE_N), ("native", NATIVE_N)):
-        knobs = {"precision": "mixed"} if precision == "mixed" else {}
-        certify = precision == "mixed"
-        for first, fam in zip((True, False), FAMILIES):
-            d, e = make_family(fam, n, seed=seed)
-            ref, limit = reference(d, e), bar(d, e)
-            # Device arrays, as eigvalsh_tridiagonal makes them: every call
-            # then takes the same front-door path.
-            d, e = jnp.asarray(d), jnp.asarray(e)
+    """The default float64 call on ``uniform`` pays the route's compile
+    and is repeated warm (no compile allowed); then each family runs
+    ``precision="mixed", certify=True`` (the same tree executable plus
+    the certificate sweep) and may not escalate."""
+    on_tpu = jax.devices()[0].platform == "tpu"
+    for fam in FAMILIES:
+        d, e = make_family(fam, LARGE_N, seed=seed)
+        ref, limit = reference(d, e), bar(d, e)
+        # Device arrays, as eigvalsh_tridiagonal makes them: every call
+        # then takes the same front-door path.
+        d, e = jnp.asarray(d), jnp.asarray(e)
+        runs = [("mixed_certified", True, {"precision": "mixed"})]
+        if fam == "uniform":
+            runs.insert(0, ("default", False, {}))
+        for name, certify, knobs in runs:
             req = SolveRequest(d=d, e=e, certify=certify, knobs=knobs)
             key = route_request(req).route
-            res, first_s, compile_s, compiles = timed(
-                lambda: execute_request(req))
+            if on_tpu and key.precision != "mixed":
+                raise AssertionError(
+                    f"float64 on TPU routed to {key.precision!r}")
+            res, first_s, compile_s, _ = timed(lambda: execute_request(req))
             diag = res.diagnostics or {}
             if diag.get("escalations"):
                 raise AssertionError(
-                    f"{precision} {fam}: degradation ladder fired: "
+                    f"{name} {fam}: degradation ladder fired: "
                     f"{diag['escalations']}")
+            if certify and diag.get("certified") != LARGE_N:
+                raise AssertionError(f"{name} {fam}: {diag}")
             err = worst_error(res.eigenvalues, ref, limit,
-                              f"{precision} {fam} n={n}")
+                              f"{name} {fam} n={LARGE_N}")
             warm_s = None
-            if first:
+            if name == "default":
                 lam, warm_s, _, compiles = timed(
-                    lambda: eigvalsh_tridiagonal(d, e, certify=certify,
-                                                 **knobs))
+                    lambda: eigvalsh_tridiagonal(d, e))
                 err = max(err, worst_error(lam, ref, limit,
-                                           f"{precision} {fam} n={n}"))
+                                           f"{name} {fam} n={LARGE_N}"))
                 if compiles:
                     raise AssertionError(f"warm solve compiled {compiles}")
-            tree = jnp.float32 if precision == "mixed" else jnp.float64
-            say("large", phase=f"{precision}/{fam}", n=n,
+            say("large", phase=f"{name}/{fam}", n=LARGE_N,
                 route=key._asdict(),
-                kernels=kernel_backends(tree, jnp.float64),
+                kernels=kernel_backends(jnp.float32, jnp.float64),
                 compile_s=compile_s, first_call_s=first_s,
                 warm_solve_s=warm_s, worst_error=err, bar=limit,
                 certified=diag.get("certified"), lanes=diag.get("lanes"),
                 peak_bytes_in_use=peak_bytes())
-        stats = executable_stats(key)
-        say("large", phase=f"{precision}/executable", n=n, **stats)
-        if precision == "mixed" and stats["tpu_custom_call"] == 0:
-            raise AssertionError("no Pallas kernel in the mixed route")
+    stats = executable_stats(key)
+    say("large", phase="mixed/executable", n=LARGE_N, **stats)
+    if stats["tpu_custom_call"] == 0:
+        raise AssertionError("no Pallas kernel in the mixed route")
 
 
 def serve_phase(seed: int) -> None:
@@ -265,9 +301,10 @@ def serve_phase(seed: int) -> None:
                   for i, (lam, (ref, limit)) in enumerate(zip(lams, refs)))
         key = route_request(SolveRequest(d=burst[0][0],
                                          e=burst[0][1])).route
+        tree = jnp.float32 if key.precision == "mixed" else jnp.float64
         say("serve", phase="slq_burst", problems=SLQ_BATCH, n=SLQ_N,
             route=key._asdict(),
-            kernels=kernel_backends(jnp.float64, jnp.float64),
+            kernels=kernel_backends(tree, jnp.float64),
             compile_s=compile_s, first_burst_s=cold_s, warm_burst_s=warm_s,
             worst_error=err, bar=max(r[1] for r in refs),
             peak_bytes_in_use=peak_bytes())
@@ -304,9 +341,7 @@ def sharded_phase(seed: int) -> None:
     d, e = make_family("uniform", SHARDED_N, seed=seed)
     ref, limit = reference(d, e), bar(d, e)
     d, e = jnp.asarray(d), jnp.asarray(e)
-    knobs = {"precision": "mixed", "certify": True}
-    key = route_request(SolveRequest(d=d, e=e, certify=True, knobs={
-        "mesh": 4, "precision": "mixed"})).route
+    key = route_request(SolveRequest(d=d, e=e, knobs={"mesh": 4})).route
     mesh = _plan._solver_mesh(key.shards)
     ids = sorted(dev.id for dev in mesh.devices.flat)
     batch = _plan._batch_sharding(4)
@@ -319,14 +354,15 @@ def sharded_phase(seed: int) -> None:
         raise AssertionError(f"mesh=4 routed to {key.shards} shards")
 
     lam4, _, cold_s, compile_s, warm_s = cold_warm(
-        lambda: eigvalsh_tridiagonal(d, e, mesh=4, **knobs))
+        lambda: eigvalsh_tridiagonal(d, e, mesh=4))
     err = worst_error(lam4, ref, limit, f"mesh=4 n={SHARDED_N}")
+    tree = jnp.float32 if key.precision == "mixed" else jnp.float64
     say("sharded", phase="mesh=4", n=SHARDED_N, route=key._asdict(),
-        kernels=kernel_backends(jnp.float32, jnp.float64),
+        kernels=kernel_backends(tree, jnp.float64),
         compile_s=compile_s, first_call_s=cold_s, warm_solve_s=warm_s,
         worst_error=err, bar=limit, peak_bytes_in_use=peak_bytes())
     lam1, _, cold_s, compile_s, warm_s = cold_warm(
-        lambda: eigvalsh_tridiagonal(d, e, mesh=1, **knobs))
+        lambda: eigvalsh_tridiagonal(d, e, mesh=1))
     err1 = worst_error(lam1, ref, limit, f"mesh=1 n={SHARDED_N}")
     diff = float(np.max(np.abs(np.asarray(lam4) - np.asarray(lam1))))
     say("sharded", phase="mesh=1", n=SHARDED_N, compile_s=compile_s,
@@ -361,6 +397,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         sharded_phase(args.seed)
     else:
+        arith_phase(args.seed)
         large_phase(args.seed)
         serve_phase(args.seed)
     say("total", seconds=time.perf_counter() - t0,

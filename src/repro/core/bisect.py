@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import guard as _guard
+from repro.core import tune as _tune
 from repro.core.instrument import SolveCounter
 
 # Bisection halvings cap.  The while_loop exits as soon as every bracket
@@ -112,6 +113,17 @@ _REFINE_MAX_LAUNCHES = 24
 REFINE_EXECUTOR_TRACES = SolveCounter("refine_executor_traces")
 
 
+def _safmin(dtype) -> float:
+    """Smallest normal number of ``dtype`` that the device keeps.  A TPU
+    emulates float64 with float32's exponent range and flushes anything
+    below 2**-126 to zero (measured on a v5e), ``finfo(float64).tiny``
+    included -- a floor of that size would vanish on the chip."""
+    tiny = float(jnp.finfo(dtype).tiny)
+    if jnp.dtype(dtype).itemsize > 4 and _tune.pinned_backend() == "tpu":
+        return max(tiny, float(jnp.finfo(jnp.float32).tiny))
+    return tiny
+
+
 def _pivot_floor(e2, dtype):
     """DSTEBZ-style pivot floor: guards the count recurrence's division.
 
@@ -120,7 +132,7 @@ def _pivot_floor(e2, dtype):
     landing exactly on an eigenvalue is replaced by ``-pivmin`` (counted
     as negative, matching LAPACK's "eigenvalues <= shift" convention).
     """
-    safmin = jnp.finfo(dtype).tiny
+    safmin = _safmin(dtype)
     emax = (jnp.max(e2, axis=-1, keepdims=True) if e2.shape[-1]
             else jnp.zeros(e2.shape[:-1] + (1,), dtype))
     return safmin * jnp.maximum(1.0, emax)
@@ -220,7 +232,7 @@ def _slice_targets(d, e, targets, *, maxiter: int = DEFAULT_MAX_BISECT,
     glo = jnp.min(d - radius, axis=1, keepdims=True) - pivmin  # (B, 1)
     ghi = jnp.max(d + radius, axis=1, keepdims=True) + pivmin
     scale = jnp.maximum(jnp.abs(glo), jnp.abs(ghi))            # ~ ||T||
-    tol = 2.0 * eps * jnp.maximum(scale, jnp.finfo(dtype).tiny) + 2.0 * pivmin
+    tol = 2.0 * eps * jnp.maximum(scale, _safmin(dtype)) + 2.0 * pivmin
 
     k = targets.shape[1]
     lo = jnp.broadcast_to(glo, (B, k))

@@ -377,7 +377,7 @@ def eigvalsh_tridiagonal_batch(d, e, *, leaf: int | None = None,
                                fused: bool = True,
                                dtype=None, mesh="auto",
                                compress_halo: bool = False,
-                               precision: str = "native",
+                               precision: str = "auto",
                                refine_tol: float | None = None
                                ) -> BRBatchResult:
     """All eigenvalues of B independent symmetric tridiagonals at once.
@@ -400,6 +400,9 @@ def eigvalsh_tridiagonal_batch(d, e, *, leaf: int | None = None,
     Returns:
       BRBatchResult with eigenvalues (B, n) ascending per problem.
     """
+    from repro.core import plan as _plan  # deferred: plan imports br_dc
+    precision = _plan.resolve_precision(
+        precision, dtype if dtype is not None else jnp.asarray(d).dtype)
     if precision == "mixed" and dtype is None:
         dtype = jnp.float64   # mixed certifies / returns in f64
     d, e = _as_batch(d, e, dtype)
@@ -410,7 +413,6 @@ def eigvalsh_tridiagonal_batch(d, e, *, leaf: int | None = None,
         return BRBatchResult(d, ones if return_boundary else None,
                              ones if return_boundary else None, ())
 
-    from repro.core import plan as _plan  # deferred: plan imports br_dc
     p = _plan.make_plan(n, B, leaf=leaf, chunk=chunk, niter=niter,
                         use_zhat=use_zhat, return_boundary=return_boundary,
                         tol_factor=tol_factor,
@@ -434,7 +436,7 @@ def eigvalsh_tridiagonal_br(d, e, *, leaf: int | None = None,
                             fused: bool = True,
                             dtype=None, mesh="auto",
                             compress_halo: bool = False,
-                            precision: str = "native",
+                            precision: str = "auto",
                             refine_tol: float | None = None) -> BRResult:
     """All eigenvalues of the symmetric tridiagonal (d, e) via boundary-row D&C.
 
@@ -449,7 +451,7 @@ def eigvalsh_tridiagonal_br(d, e, *, leaf: int | None = None,
         None consults the tuning cache (``core.tune``), falling back to
         the built-in default of 32.
       chunk: streaming chunk for secular/row updates (memory knob).
-      niter: fixed secular iteration budget.  None picks the precision's
+      niter: secular iteration cap.  None picks the precision's
         default: ``secular.DEFAULT_NITER`` for native trees,
         ``secular.DEFAULT_NITER_F32`` for f32/mixed trees (single
         precision hits its accuracy floor in fewer iterations).
@@ -484,13 +486,14 @@ def eigvalsh_tridiagonal_br(d, e, *, leaf: int | None = None,
       compress_halo: int8-compress the boundary rows in the sharded
         path's subtree->cooperative all-gather (off by default; the
         uncompressed sharded path is bit-identical to single-device).
-      precision: "native" (default) runs the tree in the input dtype;
-        "mixed" runs the ENTIRE tree -- leaves, deflation, secular
-        iteration, fused post-pass, resident kernel, sharded halo -- in
-        f32, then certifies every eigenvalue with f64 Sturm counts
-        against the original (d, e) and polishes only the non-certified
-        clusters with bracket-guarded f64 iteration
-        (``bisect.refine_clusters``).  Output is float64 with every
+      precision: "auto" (default) is "mixed" for float64 on a TPU and
+        "native" otherwise (``plan.resolve_precision``); "native" runs
+        the tree in the input dtype; "mixed" runs the ENTIRE tree --
+        leaves, deflation, secular iteration, fused post-pass, resident
+        kernel, sharded halo -- in f32, then certifies every eigenvalue
+        with f64 Sturm counts against the original (d, e) and polishes
+        only the non-certified clusters with bracket-guarded f64
+        iteration (``bisect.refine_clusters``).  Output is float64 with every
         eigenvalue within ``refine_tol * eps_f64 * ||T||_1`` of a true
         eigenvalue.  Requires x64 mode.  Boundary rows under mixed are
         f32-accurate (cast to f64, permuted with the eigenvalues) --
@@ -499,8 +502,11 @@ def eigvalsh_tridiagonal_br(d, e, *, leaf: int | None = None,
         ``eps_f64 * ||T||_1`` (default ``bisect.DEFAULT_REFINE_TOL``);
         only valid with precision="mixed".
     """
+    from repro.core import plan as _plan  # deferred: plan imports br_dc
     d = jnp.asarray(d)
     e = jnp.asarray(e)
+    precision = _plan.resolve_precision(
+        precision, dtype if dtype is not None else d.dtype)
     if precision == "mixed" and dtype is None:
         dtype = jnp.float64   # mixed certifies / returns in f64
     if dtype is not None:
@@ -512,7 +518,6 @@ def eigvalsh_tridiagonal_br(d, e, *, leaf: int | None = None,
         SOLVE_COUNTER.increment()
         return BRResult(d, one, one, ())
 
-    from repro.core import plan as _plan  # deferred: plan imports br_dc
     leaf = _plan.resolve_leaf(leaf, n, d.dtype, precision)
     N, L = _tree_shape(n, leaf)
     # Single (possibly padded) leaf trees carry their boundary rows for

@@ -181,15 +181,19 @@ def _resolve_shards(mesh, padded_n: int, leaf: int) -> int:
     return shards
 
 
-def _default_leaf(dtype_name: str, precision: str) -> int:
-    """Built-in leaf size.  A native float64 tree on TPU starts from 1x1
-    leaves: the TPU's float64 ``eigh`` (XLA's Jacobi expansion in emulated
-    f64) leaves leaf eigenvalues ~7e-8 off (measured on a v5e), while the
-    tree's own merges keep float64 accuracy."""
-    if (dtype_name == "float64" and precision == "native"
-            and _tune.pinned_backend() == "tpu"):
-        return 1
-    return LEAF_DEFAULT
+def default_dtype():
+    """The dtype a request without one solves in: float64 under x64."""
+    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+
+
+def resolve_precision(precision: str, dtype=None) -> str:
+    """Resolve the ``precision`` knob: "native" and "mixed" stand; "auto"
+    (the default) is ``tune.default_precision`` of the solve dtype --
+    "mixed" for float64 on a TPU, "native" otherwise."""
+    if precision != "auto":
+        return precision
+    dtype = default_dtype() if dtype is None else dtype
+    return _tune.default_precision(jnp.dtype(dtype).name)
 
 
 def _tuned_leaf(n: int, dtype_name: str, precision: str):
@@ -197,16 +201,19 @@ def _tuned_leaf(n: int, dtype_name: str, precision: str):
 
     The coordinate is keyed by the padded size under ``LEAF_DEFAULT``
     (the only tree shape known before the knob resolves); the tuner
-    writes its ``leaf`` winner under the same coordinate.
+    writes its ``leaf`` winner under the same coordinate.  A leaf that
+    accuracy pins (``tune.pinned_leaf``) never consults the cache.
     """
+    pinned = _tune.pinned_leaf(dtype_name, precision)
+    if pinned is not None:
+        return pinned, False
     N0, _ = _br._tree_shape(n, LEAF_DEFAULT)
     tuned = _tune.lookup("solve", n=N0, dtype=dtype_name,
                          precision=precision)
-    default = _default_leaf(dtype_name, precision)
-    return int(tuned.get("leaf", default)), "leaf" in tuned
+    return int(tuned.get("leaf", LEAF_DEFAULT)), "leaf" in tuned
 
 
-def resolve_leaf(leaf, n: int, dtype, precision: str = "native") -> int:
+def resolve_leaf(leaf, n: int, dtype, precision: str = "auto") -> int:
     """Resolve the ``leaf`` knob exactly like ``resolve_solve_route``.
 
     Shared with request routing's single-leaf (L == 0) boundary-row
@@ -215,6 +222,7 @@ def resolve_leaf(leaf, n: int, dtype, precision: str = "native") -> int:
     """
     if leaf is not None:
         return int(leaf)
+    precision = resolve_precision(precision, dtype)
     return _tuned_leaf(n, jnp.dtype(dtype).name, precision)[0]
 
 
@@ -230,7 +238,7 @@ def resolve_solve_route(n: int, *, leaf: int | None = None,
                         fused: bool = True, dtype=None,
                         mesh="auto",
                         compress_halo: bool = False,
-                        precision: str = "native",
+                        precision: str = "auto",
                         refine_tol: float | None = None,
                         certify: bool = False) -> PlanKey:
     """Resolve a full-spectrum request to its bucketed route key -- pure.
@@ -258,15 +266,18 @@ def resolve_solve_route(n: int, *, leaf: int | None = None,
     tree runs in f32 (the ``dtype`` field stays the OUTPUT dtype,
     float64) and the eigenvalues are Sturm-certified / cluster-polished
     to ``refine_tol * eps_f64 * ||T||`` against the original (d, e).
+    The default ``precision="auto"`` is :func:`resolve_precision`: mixed
+    for float64 on a TPU, native otherwise; the key holds the result.
     ``niter=None`` resolves to the precision's default iteration budget
     (f32 trees hit their accuracy floor earlier -- see
     ``secular.DEFAULT_NITER_F32``); an explicit niter always wins.
     """
     if n < 1:
         raise ValueError(f"n must be >= 1, got {n}")
-    if precision not in ("native", "mixed"):
-        raise ValueError(
-            f"precision must be 'native' or 'mixed', got {precision!r}")
+    if precision not in ("auto", "native", "mixed"):
+        raise ValueError(f"precision must be 'auto', 'native' or 'mixed', "
+                         f"got {precision!r}")
+    precision = resolve_precision(precision, dtype)
     if precision == "mixed":
         if not jax.config.jax_enable_x64:
             raise ValueError(
@@ -305,7 +316,7 @@ def resolve_solve_route(n: int, *, leaf: int | None = None,
         niter = (_sec.DEFAULT_NITER_F32 if precision == "mixed"
                  else _sec.DEFAULT_NITER)
     if dtype is None:
-        dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        dtype = default_dtype()
     dtype_name = jnp.dtype(dtype).name
     # Tuning-cache consult: every knob that arrives as None resolves
     # tuned-winner-first, backend-default second -- the same seam prewarm
@@ -825,7 +836,7 @@ def make_plan(n: int, batch: int = 1, *, leaf: int | None = None,
               resident_threshold: int | None = None, fused: bool = True,
               dtype=None, mesh="auto",
               compress_halo: bool = False,
-              precision: str = "native",
+              precision: str = "auto",
               refine_tol: float | None = None) -> SolvePlan:
     """Build (or fetch) the SolvePlan for an (n, batch) request class.
 
@@ -904,7 +915,7 @@ def resolve_range_route(n: int, k: int, *, maxiter: int | None = None,
     if not (1 <= k <= n):
         raise ValueError(f"k must be in [1, n]; got k={k}, n={n}")
     if dtype is None:
-        dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+        dtype = default_dtype()
     dtype_name = jnp.dtype(dtype).name
     k_bucket = min(batch_bucket(k), n)
     if maxiter is None or polish is None:

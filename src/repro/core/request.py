@@ -70,9 +70,10 @@ class SolveRequest:
     coalesces same-mesh traffic and never mixes mesh shapes in a flush.
 
     So does the mixed-precision pipeline: "br" requests accept
-    ``precision`` ("native"/"mixed") and ``refine_tol``; both land in the
-    route key, so mixed traffic coalesces with (only) other mixed traffic
-    of the same tolerance and prewarms its own executables.  Mixed
+    ``precision`` ("auto"/"native"/"mixed") and ``refine_tol``; both land
+    in the route key ("auto" resolved), so mixed traffic coalesces with
+    (only) other mixed traffic of the same tolerance and prewarms its own
+    executables.  Mixed
     requests with no explicit dtype normalize to float64 (the output
     dtype) before routing.
 
@@ -328,7 +329,7 @@ def route_request(req: SolveRequest) -> RoutedRequest:
             # that L == 0 always yields (blo, bhi).
             from repro.core.br_dc import _tree_shape
             leaf = _plan.resolve_leaf(kw.get("leaf"), n, d.dtype,
-                                      kw.get("precision", "native"))
+                                      kw.get("precision", "auto"))
             return_boundary = return_boundary or _tree_shape(n, leaf)[1] == 0
         route = _plan.resolve_solve_route(
             n, return_boundary=return_boundary, dtype=d.dtype,
@@ -389,6 +390,7 @@ def _native_knobs(req: SolveRequest) -> dict:
             "return_boundary")
     kw = {k: v for k, v in req.knobs.items() if k not in drop}
     kw["mesh"] = None
+    kw["precision"] = "native"
     return kw
 
 

@@ -15,11 +15,12 @@ d_origin) - tau`` never suffer catastrophic cancellation near the pole.
 
 The iteration is a safeguarded fixed-weight (two-pole rational
 interpolation) scheme in the spirit of LAPACK's DLAED4, with a bisection
-bracket that guarantees convergence within the fixed iteration budget
+bracket that guarantees convergence within the iteration budget
 (bisection alone contracts the bracket by 2^-niter; the rational step is
-superlinear once close).  A fixed budget keeps the whole solver jit- and
-vmap-compatible (no per-root early exit), which is the TPU/XLA adaptation
-of the paper's per-root CUDA loops.
+superlinear once close).  The budget caps one loop per chunk of roots,
+which ends early once every root of the chunk is at a fixed point (no
+per-root early exit): jit- and vmap-compatible, the TPU/XLA adaptation of
+the paper's per-root CUDA loops.
 
 Memory: all evaluations are chunked over roots -- peak temporary is
 O(chunk * K), never O(K^2).  This is the JAX realization of the paper's
@@ -57,7 +58,9 @@ import jax.numpy as jnp
 # some roots -- DLAED4's own MAXIT: at 16 the native f64 tree missed them
 # by ~4e-8 from n = 64 on (3e7 x the 64 eps ||T|| bar at n = 1024), at 24
 # by 969 x the bar at n = 512; at 32 glued Wilkinson sits inside the bar
-# at n = 256 through 4096.
+# at n = 256 through 4096.  The budget is a cap, not a count: a chunk's
+# loop ends once every active root sits on a fixed point, so roots that
+# converge early stop paying for the crawlers' budget.
 DEFAULT_NITER = 32
 
 # The f32-aware budget for single-precision trees (the mixed-precision
@@ -242,9 +245,25 @@ def _solve_chunk(jc, d, z2, rho, kprime, niter):
         tau_next = jnp.where(g == 0.0, tau, tau_next)
         return tau_next, lo, hi, best_tau, best_g
 
+    # The budget is an upper bound: the loop stops as soon as every active
+    # root of the chunk sits on a fixed point of `body` (state unchanged,
+    # NaN counted equal), after which further steps could change nothing
+    # -- so results are bit-identical to running all niter steps.
+    # Inactive roots are discarded below and never hold the loop open.
+    def moved(new, old):
+        return jnp.any(jnp.stack([
+            jnp.any(active_root & (a != b) & ~(jnp.isnan(a) & jnp.isnan(b)))
+            for a, b in zip(new, old)]))
+
+    def step(carry):
+        k, state, _ = carry
+        new = body(k, state)
+        return k + 1, new, moved(new, state)
+
     big = jnp.full_like(tau0, jnp.inf)
-    tau, lo, hi, best_tau, best_g = jax.lax.fori_loop(
-        0, niter, body, (tau0, lo, hi, tau0, big))
+    _, (tau, lo, hi, best_tau, best_g), _ = jax.lax.while_loop(
+        lambda carry: (carry[0] < niter) & carry[2], step,
+        (jnp.int32(0), (tau0, lo, hi, tau0, big), True))
     # Final evaluation so the last tau competes with the best seen.
     g_fin, _ = _eval_g(tau, d_shift, z2, rho, active_mask)
     tau = jnp.where(jnp.abs(g_fin) < best_g, tau, best_tau)
@@ -268,7 +287,7 @@ def secular_solve(d, z2, rho, kprime, *, niter: int = DEFAULT_NITER,
       z2: (K,) squared secular weights; exactly zero outside the active set.
       rho: positive scalar.
       kprime: traced int32 -- number of active (non-deflated) poles.
-      niter: fixed safeguarded-iteration budget (see ``DEFAULT_NITER`` for
+      niter: safeguarded-iteration cap (see ``DEFAULT_NITER`` for
         the accuracy-vs-iterations tradeoff).
       chunk: roots per streamed chunk (memory = O(chunk * K)).
       dense: solve every root in one vectorized batch (no streaming loop;

@@ -136,6 +136,34 @@ def backend_defaults() -> dict:
     return _DEFAULTS
 
 
+def default_precision(dtype_name: str) -> str:
+    """What ``precision="auto"`` (the solvers' default) resolves to.
+
+    On a TPU, float64 work runs the mixed route (f32 tree, then float64
+    Sturm certification and polish): XLA emulates float64 there, and a
+    native float64 tree misses the 64 eps_f64 bar on clustered spectra
+    (README "Backend selection").  Everywhere else, and for every other
+    dtype, the tree runs natively.
+    """
+    if dtype_name == "float64" and pinned_backend() == "tpu":
+        return "mixed"
+    return "native"
+
+
+def pinned_leaf(dtype_name: str, precision: str) -> int | None:
+    """The leaf size accuracy fixes, or None when the tuner may pick it.
+
+    A native float64 tree on a TPU starts from 1x1 leaves: JAX lowers a
+    small ``eigh`` there to a Jacobi call with a fixed 1e-6 tolerance, so
+    float64 leaf eigenvalues come back ~7e-8 off.  Neither the tuning
+    cache nor the tuner may move it.
+    """
+    if (dtype_name == "float64" and precision == "native"
+            and pinned_backend() == "tpu"):
+        return 1
+    return None
+
+
 def _reset_backend_pin_for_tests() -> None:
     """Drop the backend pin + defaults memo (unit tests only)."""
     global _BACKEND, _DEFAULTS
@@ -414,10 +442,13 @@ def _tune_solve(spec: dict, iters: int, log) -> dict:
 
     n = int(spec["n"])
     batch = int(spec.get("batch", 1))
-    precision = spec.get("precision", "native")
     mesh = spec.get("mesh")
+    dtype_name = jnp.dtype(spec.get("dtype") or _plan.default_dtype()).name
+    precision = _plan.resolve_precision(spec.get("precision", "auto"),
+                                        dtype_name)
+    pinned = pinned_leaf(dtype_name, precision)
     defaults = backend_defaults()
-    base = {"leaf": int(spec.get("leaf", defaults["leaf"])),
+    base = {"leaf": int(spec.get("leaf", pinned or defaults["leaf"])),
             "chunk": defaults["chunk"],
             "stream_threshold": defaults["stream_threshold"],
             "deflate_budget": defaults["deflate_budget"],
@@ -441,9 +472,8 @@ def _tune_solve(spec: dict, iters: int, log) -> dict:
         p = plan_of(knobs)
         return lambda: p.execute(d, e).eigenvalues
 
-    current = _descend(base, [("leaf", [lf for lf in _LEAF_GRID
-                                        if lf <= max(n, 1)])],
-                       make_fn, iters)
+    leaves = [] if pinned else [lf for lf in _LEAF_GRID if lf <= max(n, 1)]
+    current = _descend(base, [("leaf", leaves)], make_fn, iters)
     current = _descend(current, _solve_grids(key0.padded_n, defaults),
                        make_fn, iters)
 

@@ -4,10 +4,6 @@
                          analogue; grid over root blocks, pole-tile loop)
   fused_update.py     -- fused conquer post-pass: one delta sweep emits the
                          Gu-Eisenstat weights AND the selected-row update
-  boundary_update.py  -- streamed 2-row selected-row update (legacy
-                         two-pass form; reference/benchmark baseline)
-  zhat.py             -- Gu-Eisenstat stable weight reconstruction (legacy
-                         two-pass form)
   sturm_count.py      -- batched Sturm-sequence eigenvalue counts for the
                          spectrum-slicing front end (grid over problems x
                          probe-shift blocks)
@@ -20,7 +16,6 @@ dense oracles.
 """
 
 from repro.kernels.ops import (
-    boundary_rows_update,
     resolve_backend,
     secular_postpass,
     secular_postpass_batched,
@@ -28,22 +23,18 @@ from repro.kernels.ops import (
     secular_solve_batched,
     set_backend,
     sturm_count_batched,
-    zhat_reconstruct,
 )
 from repro.kernels.sturm_count import sturm_count_pallas_batch
 from repro.kernels.secular_roots import (secular_solve_pallas,
                                          secular_solve_pallas_batch)
-from repro.kernels.boundary_update import boundary_rows_update_pallas
 from repro.kernels.fused_update import (secular_postpass_pallas,
                                         secular_postpass_pallas_batch)
-from repro.kernels.zhat import zhat_reconstruct_pallas
 
 __all__ = [
-    "boundary_rows_update", "boundary_rows_update_pallas", "resolve_backend",
+    "resolve_backend",
     "secular_postpass", "secular_postpass_batched", "secular_postpass_pallas",
     "secular_postpass_pallas_batch",
     "secular_solve", "secular_solve_batched", "secular_solve_pallas",
     "secular_solve_pallas_batch", "set_backend",
     "sturm_count_batched", "sturm_count_pallas_batch",
-    "zhat_reconstruct", "zhat_reconstruct_pallas",
 ]

@@ -17,11 +17,6 @@ trace-time function of (device backend, dtype) -- never a try/except --
 and an explicit ``set_backend("pallas")`` with a float64 operand on a TPU
 raises instead of changing path.
 
-The legacy two-pass kernels (``boundary_rows_update``,
-``zhat_reconstruct``; superseded by the fused post-pass) keep 1-D blocks
-and gathers that Mosaic refuses, so "auto" never selects them: they run
-as Pallas only when asked for explicitly.
-
 Size-adaptive dispatch: the merge ops take ``dense=`` -- when True (small
 K, decided per merge-tree level by ``stream_threshold``) the op runs the
 dense vectorized XLA path regardless of backend.  Small merges are
@@ -39,14 +34,12 @@ from repro.core import secular as _sec
 from repro.core.secular import DEFAULT_NITER, DEFAULT_NITER_F32
 from repro.kernels.secular_roots import (secular_solve_pallas,
                                          secular_solve_pallas_batch)
-from repro.kernels.boundary_update import boundary_rows_update_pallas
 from repro.kernels.fused_update import (secular_postpass_pallas,
                                         secular_postpass_pallas_batch)
 from repro.kernels.resident_merge import (resident_merge_pallas,
                                           resident_merge_pallas_batch)
 from repro.kernels.sturm_count import (DEFAULT_SHIFT_BLOCK,
                                        sturm_count_pallas_batch)
-from repro.kernels.zhat import zhat_reconstruct_pallas
 
 _BACKEND = "auto"
 
@@ -65,12 +58,10 @@ def _device_backend() -> str:
     return _tune.pinned_backend()
 
 
-def resolve_backend(dtype, backend: str | None = None, *,
-                    auto_pallas: bool = True) -> str:
+def resolve_backend(dtype, backend: str | None = None) -> str:
     """The backend one dispatch runs on, from its operand dtype.
 
     ``backend`` overrides the process-wide :func:`set_backend` choice.
-    ``auto_pallas=False`` marks a kernel that "auto" never selects.
     Raises ValueError for an explicit "pallas" with a 64-bit operand on a
     TPU (Mosaic has no 64-bit scalars).
     """
@@ -78,7 +69,7 @@ def resolve_backend(dtype, backend: str | None = None, *,
     on_tpu = _device_backend() == "tpu"
     wide = jnp.dtype(dtype).itemsize > 4
     if b == "auto":
-        return "pallas" if on_tpu and auto_pallas and not wide else "xla"
+        return "pallas" if on_tpu and not wide else "xla"
     if b == "pallas" and on_tpu and wide:
         raise ValueError(
             f"backend='pallas' cannot run {jnp.dtype(dtype).name} operands "
@@ -235,20 +226,3 @@ def sturm_count_batched(d, e2, shifts, pivmin, *,
                                         interpret=_interpret())
     return _bis.sturm_count_xla(d, e2, shifts, pivmin)
 
-
-def boundary_rows_update(R, d, z, origin, tau, kprime, *, chunk: int = 256,
-                         backend: str | None = None):
-    if resolve_backend(d.dtype, backend, auto_pallas=False) == "pallas":
-        return boundary_rows_update_pallas(R, d, z, origin, tau, kprime,
-                                           root_block=chunk,
-                                           interpret=_interpret())
-    return _sec.boundary_rows_update(R, d, z, origin, tau, kprime, chunk=chunk)
-
-
-def zhat_reconstruct(d, z, origin, tau, kprime, rho, *, chunk: int = 256,
-                     backend: str | None = None):
-    if resolve_backend(d.dtype, backend, auto_pallas=False) == "pallas":
-        return zhat_reconstruct_pallas(d, z, origin, tau, kprime, rho,
-                                       pole_block=chunk,
-                                       interpret=_interpret())
-    return _sec.zhat_reconstruct(d, z, origin, tau, kprime, rho, chunk=chunk)

@@ -13,11 +13,9 @@ import jax.numpy as jnp
 from repro.core import secular as sec
 from repro.kernels import ref
 from repro.kernels.secular_roots import secular_solve_pallas
-from repro.kernels.boundary_update import boundary_rows_update_pallas
 from repro.kernels.fused_update import secular_postpass_pallas
 from repro.kernels.resident_merge import (resident_merge_pallas,
                                           resident_merge_pallas_batch)
-from repro.kernels.zhat import zhat_reconstruct_pallas
 
 
 def _problem(K, kprime, seed=0, dtype=np.float64):
@@ -86,8 +84,8 @@ def test_boundary_update_kernel(K, kprime, r):
     d, z, rho = _problem(K, kprime, seed=3)
     origin, tau = sec.secular_solve(d, z * z, rho, kprime, niter=16)
     R = jnp.asarray(rng.standard_normal((r, K)))
-    got = boundary_rows_update_pallas(R, d, z, origin, tau,
-                                      jnp.asarray(kprime), interpret=True)
+    got = sec.boundary_rows_update(R, d, z, origin, tau,
+                                   jnp.asarray(kprime), chunk=32)
     want = ref.boundary_rows_update_ref(R, d, z, origin, tau, kprime)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-12, rtol=1e-12)
@@ -97,8 +95,8 @@ def test_boundary_update_kernel(K, kprime, r):
 def test_zhat_kernel(K, kprime):
     d, z, rho = _problem(K, kprime, seed=4)
     origin, tau = sec.secular_solve(d, z * z, rho, kprime, niter=16)
-    got = zhat_reconstruct_pallas(d, z, origin, tau, jnp.asarray(kprime),
-                                  jnp.asarray(rho, d.dtype), interpret=True)
+    got = sec.zhat_reconstruct(d, z, origin, tau, jnp.asarray(kprime),
+                               jnp.asarray(rho, d.dtype), chunk=32)
     want = ref.zhat_reconstruct_ref(d, z, origin, tau, kprime, rho)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-10, rtol=1e-8)
@@ -323,7 +321,7 @@ def test_zhat_improves_or_matches_weights():
     well-conditioned problem (sanity on the log-product path)."""
     d, z, rho = _problem(64, 64, seed=5)
     origin, tau = sec.secular_solve(d, z * z, rho, 64, niter=24)
-    zhat = zhat_reconstruct_pallas(d, z, origin, tau, jnp.asarray(64),
-                                   jnp.asarray(rho, d.dtype), interpret=True)
+    zhat = sec.zhat_reconstruct(d, z, origin, tau, jnp.asarray(64),
+                                jnp.asarray(rho, d.dtype))
     np.testing.assert_allclose(np.asarray(zhat), np.asarray(z),
                                atol=1e-8, rtol=1e-6)
