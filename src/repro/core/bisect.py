@@ -58,7 +58,7 @@ import numpy as np
 
 from repro.core import guard as _guard
 from repro.core import tune as _tune
-from repro.core.instrument import SolveCounter
+from repro.core.instrument import SolveCounter, span
 
 # Bisection halvings cap.  The while_loop exits as soon as every bracket
 # is below its tolerance (~53 + log2(spread/scale) halvings at float64);
@@ -562,6 +562,11 @@ def _refine_misses(d, e2, lamh, loh, hih, tol_dev, miss):
     Freeze-per-bracket makes per-lane trajectories independent of the
     compaction schedule, so results are deterministic.  Returns total
     polish iterations.
+
+    Each launch is three spans: ``repro.polish.compact`` (the gather and
+    transfer of the ``live`` lanes into ``width`` launched lanes),
+    ``repro.polish.sweep`` (the launch and the wait for its outputs) and
+    ``repro.polish.scatter``.
     """
     B, n = miss.shape
     xph = lamh.copy()      # xp == x: no secant history yet
@@ -569,31 +574,36 @@ def _refine_misses(d, e2, lamh, loh, hih, tol_dev, miss):
     modeh = np.zeros(lamh.shape, np.int32)
     idxs = [np.nonzero(miss[b])[0].astype(np.int32) for b in range(B)]
     iters = 0
-    for _ in range(_REFINE_MAX_LAUNCHES):
-        kmax = max(len(ix) for ix in idxs)
+    for launch in range(_REFINE_MAX_LAUNCHES):
+        sizes = [len(ix) for ix in idxs]
+        kmax = max(sizes)
         if kmax == 0:
             break
         k = min(_bucket(kmax), n)
-        gidx = np.zeros((B, k), np.int32)
-        live = np.zeros((B, k), bool)
-        for b, ix in enumerate(idxs):
-            gidx[b, :len(ix)] = ix
-            live[b, :len(ix)] = True
-        take = lambda a: jnp.asarray(np.take_along_axis(a, gidx, axis=1))
-        x1, lo1, hi1, xp1, gp1, mode1, live1, its = _refine_executor(
-            d, e2, take(lamh), take(loh), take(hih), take(xph), take(gph),
-            take(modeh), jnp.asarray(gidx), jnp.asarray(live), tol_dev,
-            maxiter=_REFINE_TRIPS)
-        iters += int(its)
-        x1, lo1, hi1 = np.asarray(x1), np.asarray(lo1), np.asarray(hi1)
-        xp1, gp1, live1 = np.asarray(xp1), np.asarray(gp1), np.asarray(live1)
-        mode1 = np.asarray(mode1)
-        for b in range(B):
-            ix = gidx[b, live[b]]
-            for src, dst in ((x1, lamh), (lo1, loh), (hi1, hih),
-                             (xp1, xph), (gp1, gph), (mode1, modeh)):
-                dst[b, ix] = src[b, live[b]]
-            idxs[b] = gidx[b, live[b] & live1[b]]
+        with span("repro.polish.compact", launch=launch, live=sum(sizes),
+                  width=B * k):
+            gidx = np.zeros((B, k), np.int32)
+            live = np.zeros((B, k), bool)
+            for b, ix in enumerate(idxs):
+                gidx[b, :len(ix)] = ix
+                live[b, :len(ix)] = True
+            lanes = [jnp.asarray(np.take_along_axis(a, gidx, axis=1))
+                     for a in (lamh, loh, hih, xph, gph, modeh)]
+            lanes += [jnp.asarray(gidx), jnp.asarray(live)]
+        with span("repro.polish.sweep", launch=launch):
+            x1, lo1, hi1, xp1, gp1, mode1, live1, its = _refine_executor(
+                d, e2, *lanes, tol_dev, maxiter=_REFINE_TRIPS)
+            iters += int(its)
+            x1, lo1, hi1 = np.asarray(x1), np.asarray(lo1), np.asarray(hi1)
+            xp1, gp1 = np.asarray(xp1), np.asarray(gp1)
+            live1, mode1 = np.asarray(live1), np.asarray(mode1)
+        with span("repro.polish.scatter", launch=launch):
+            for b in range(B):
+                ix = gidx[b, live[b]]
+                for src, dst in ((x1, lamh), (lo1, loh), (hi1, hih),
+                                 (xp1, xph), (gp1, gph), (mode1, modeh)):
+                    dst[b, ix] = src[b, live[b]]
+                idxs[b] = gidx[b, live[b] & live1[b]]
     return iters
 
 
@@ -653,18 +663,21 @@ def refine_clusters(d, e, lam, *, nvalid=None,
     polished_mask = np.zeros((B, n), bool)
     iters = 0
     rounds_used = 0
-    lamh = None
-    for _ in range(max(1, int(rounds))):
-        cert, lo, hi, tol_dev = _certify_executor(d, e2, lam, nvalid_arr,
-                                                  tol_arr)
-        miss = ~np.asarray(cert)
+    for r in range(max(1, int(rounds))):
+        # The span holds the certify launch and the host read of its
+        # outputs: the mask, and the state the polish needs on a miss.
+        with span("repro.polish.certify", round=r):
+            cert, lo, hi, tol_dev = _certify_executor(d, e2, lam,
+                                                      nvalid_arr, tol_arr)
+            miss = ~np.asarray(cert)
+            if miss.any():
+                lamh = np.asarray(lam).copy()
+                loh, hih = np.asarray(lo).copy(), np.asarray(hi).copy()
         if not miss.any():
             break
         rounds_used += 1
         polished_mask |= miss
-        lamh = np.asarray(lam).copy()
-        iters += _refine_misses(d, e2, lamh, np.asarray(lo).copy(),
-                                np.asarray(hi).copy(), tol_dev, miss)
+        iters += _refine_misses(d, e2, lamh, loh, hih, tol_dev, miss)
         lam = jnp.asarray(lamh)
     if sort:
         lam = jnp.sort(lam, axis=1)

@@ -31,6 +31,14 @@ tridiagonal, submit -> result).  Like the degradation gauge it is
 ungated and bounded: probes are rare (one per governor period) and each
 latency is the number the <10% monitor-overhead budget is audited
 against.
+
+**Spans** (:func:`span`) name the host work between device launches.
+They are ``jax.profiler.TraceAnnotation`` events, so they land in the
+profiler trace on the clock the device ops share, and cost one
+sub-microsecond check each when no profiler is running.  Every span is
+named ``repro.<layer>[.<step>]`` and carries as stats only values the
+host already holds; the spans of one served flush share its ``flush``
+index.
 """
 
 from __future__ import annotations
@@ -38,15 +46,27 @@ from __future__ import annotations
 import contextlib
 import threading
 
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str, **stats):
+    """Context manager recording the enclosed host work as the span
+    ``name`` in the profiler trace, with ``stats`` (numbers or strings
+    the host already holds) attached.  A stat known only inside the span
+    is added with ``set_metadata(**stats)`` on the returned object.
+    The only way program code records a span: it never forces a device
+    sync, and it records nothing unless a profiler trace is running."""
+    return TraceAnnotation(name, **stats)
+
 
 class LatencyRecorder:
     """Thread-safe bounded sample buffer with percentile readout.
 
-    The serving layer records one sample per request (submit -> demux)
-    and per flush; ``percentile`` uses the nearest-rank convention on a
-    sorted copy, so p50/p99 match what a load generator would report.
-    Bounded (drops oldest beyond ``maxlen``) so a long-lived service
-    never grows its metrics without bound.
+    The serving layer records one sample per request (submit -> demux);
+    ``percentile`` uses the nearest-rank convention on a sorted copy, so
+    p50/p99 match what a load generator would report.  Bounded (drops
+    oldest beyond ``maxlen``) so a long-lived service never grows its
+    metrics without bound.
     """
 
     def __init__(self, maxlen: int = 4096):

@@ -53,7 +53,7 @@ from repro.core import guard as _guard
 from repro.core import merge as _merge
 from repro.core import secular as _sec
 from repro.core import tune as _tune
-from repro.core.instrument import SolveCounter
+from repro.core.instrument import SolveCounter, span
 from repro.runtime import faults as _faults
 
 # Built-in leaf block size; ``leaf=None`` resolves to the tuning cache's
@@ -549,102 +549,105 @@ class SolvePlan:
         """
         key = self.key
         dtype = jnp.dtype(key.dtype)
-        d = jnp.asarray(d, dtype)
-        e = jnp.asarray(e, dtype)
-        d, e = _br._as_batch(d, e, None)   # enforce (B, n)/(B, n-1)
-        B, n = d.shape
-        Bb = key.batch_bucket
-        if B > Bb:
-            raise ValueError(
-                f"batch {B} exceeds plan bucket {Bb}; make a bigger plan")
-        if _br._tree_shape(n, key.leaf)[0] != key.padded_n:
-            raise ValueError(
-                f"n={n} pads to {_br._tree_shape(n, key.leaf)[0]}, but this "
-                f"plan was built for padded N={key.padded_n}")
-
-        if orig_n is not None:
-            orig_n = jnp.asarray(orig_n, jnp.int32)
-            if orig_n.shape != (B,):
+        with span("repro.plan.stage"):
+            d = jnp.asarray(d, dtype)
+            e = jnp.asarray(e, dtype)
+            d, e = _br._as_batch(d, e, None)   # enforce (B, n)/(B, n-1)
+            B, n = d.shape
+            Bb = key.batch_bucket
+            if B > Bb:
                 raise ValueError(
-                    f"orig_n must have shape ({B},), got {orig_n.shape}")
+                    f"batch {B} exceeds plan bucket {Bb}; make a bigger "
+                    f"plan")
+            if _br._tree_shape(n, key.leaf)[0] != key.padded_n:
+                raise ValueError(
+                    f"n={n} pads to {_br._tree_shape(n, key.leaf)[0]}, but "
+                    f"this plan was built for padded N={key.padded_n}")
 
-        if B < Bb:
-            # Dummy problems: zero diagonals decouple exactly and cost one
-            # deflated pass-through per merge; sliced off below.
-            d = jnp.concatenate([d, jnp.zeros((Bb - B, n), dtype)], axis=0)
-            e = jnp.concatenate(
-                [e, jnp.zeros((Bb - B, max(n - 1, 0)), dtype)], axis=0)
+            if orig_n is not None:
+                orig_n = jnp.asarray(orig_n, jnp.int32)
+                if orig_n.shape != (B,):
+                    raise ValueError(
+                        f"orig_n must have shape ({B},), got {orig_n.shape}")
 
-        d_pad, e_pad, N, L = _br._pad_problem(d, e, key.leaf)
-        # The tracked third row slot is only needed when padding appends
-        # sentinel rows below row n-1; unpadded problems (n == N) already
-        # carry that row as the bhi slot, so they run with r == 2.  With
-        # per-problem original sizes the track slot always runs (some
-        # problems may be host-padded even when n == N) and each problem
-        # follows its own row orig_n[b] - 1.
-        if key.return_boundary and orig_n is not None:
-            track = jnp.concatenate(
-                [orig_n - 1, jnp.full((Bb - B,), n - 1, jnp.int32)])
-        elif key.return_boundary and n != N:
-            track = jnp.full((Bb,), n - 1, jnp.int32)
-        else:
-            track = None
+            if B < Bb:
+                # Dummy problems: zero diagonals decouple exactly and cost
+                # one deflated pass-through per merge; sliced off below.
+                d = jnp.concatenate([d, jnp.zeros((Bb - B, n), dtype)],
+                                    axis=0)
+                e = jnp.concatenate(
+                    [e, jnp.zeros((Bb - B, max(n - 1, 0)), dtype)], axis=0)
 
-        if key.precision == "mixed":
-            # The whole D&C tree runs in f32; the f64 (d_pad, e_pad) stay
-            # behind for the Sturm certification / cluster polish below.
-            d_run = d_pad.astype(jnp.float32)
-            e_run = e_pad.astype(jnp.float32)
-        else:
-            d_run, e_run = d_pad, e_pad
+            d_pad, e_pad, N, L = _br._pad_problem(d, e, key.leaf)
+            # The tracked third row slot is only needed when padding
+            # appends sentinel rows below row n-1; unpadded problems
+            # (n == N) already carry that row as the bhi slot, so they run
+            # with r == 2.  With per-problem original sizes the track slot
+            # always runs (some problems may be host-padded even when
+            # n == N) and each problem follows its own row orig_n[b] - 1.
+            if key.return_boundary and orig_n is not None:
+                track = jnp.concatenate(
+                    [orig_n - 1, jnp.full((Bb - B,), n - 1, jnp.int32)])
+            elif key.return_boundary and n != N:
+                track = jnp.full((Bb,), n - 1, jnp.int32)
+            else:
+                track = None
 
-        # Chaos-harness hook: a scheduled launch fault raises here --
-        # after input staging, before any executor runs -- exactly where
-        # a real device/compile fault would surface to the caller.  The
-        # hook is one global-flag read when no schedule is configured.
-        _faults.inject("plan.launch")
+            if key.precision == "mixed":
+                # The whole D&C tree runs in f32; the f64 (d_pad, e_pad)
+                # stay behind for the Sturm certification / cluster polish
+                # below.
+                d_run = d_pad.astype(jnp.float32)
+                e_run = e_pad.astype(jnp.float32)
+            else:
+                d_run, e_run = d_pad, e_pad
 
-        if key.shards > 1:
-            # Distributed conquer: the *problem* axis is sharded over the
-            # 1-D solver mesh (batch sharding does not compose with it --
-            # every device works on every problem's slice).
-            mesh = _solver_mesh(key.shards)
-            sliced = NamedSharding(
-                mesh, PartitionSpec(None, _dist_axis()))
-            # Chaos-harness hook: corrupts one staged off-diagonal entry
-            # (default: the last, a shard-boundary coupling) -- the "halo
-            # exchange delivered a damaged value" scenario.
-            e_run = _faults.corrupt_entry("dist.halo", e_run)
-            d_run = jax.device_put(d_run, sliced)
-            e_run = jax.device_put(e_run, sliced)
-            if track is not None:
-                track = jax.device_put(
-                    track, NamedSharding(mesh, PartitionSpec()))
-            lam, rows, kprimes = _executor_sharded(
-                d_run, e_run, track, mesh=mesh, shards=key.shards,
-                compress_halo=key.compress_halo, leaf=key.leaf,
-                chunk=key.chunk, niter=key.niter, use_zhat=key.use_zhat,
-                return_boundary=key.return_boundary,
-                tol_factor=key.tol_factor,
-                stream_threshold=key.stream_threshold,
-                deflate_budget=key.deflate_budget,
-                resident_threshold=key.resident_threshold, fused=key.fused)
-        else:
-            sharding = _batch_sharding(Bb)
-            if sharding is not None:
-                d_run = jax.device_put(d_run, sharding)
-                e_run = jax.device_put(e_run, sharding)
+            # Chaos-harness hook: a scheduled launch fault raises here --
+            # after input staging, before any executor runs -- exactly
+            # where a real device/compile fault would surface to the
+            # caller.  The hook is one global-flag read when no schedule
+            # is configured.
+            _faults.inject("plan.launch")
+
+            if key.shards > 1:
+                # Distributed conquer: the *problem* axis is sharded over
+                # the 1-D solver mesh (batch sharding does not compose with
+                # it -- every device works on every problem's slice).
+                mesh = _solver_mesh(key.shards)
+                sliced = NamedSharding(
+                    mesh, PartitionSpec(None, _dist_axis()))
+                # Chaos-harness hook: corrupts one staged off-diagonal
+                # entry (default: the last, a shard-boundary coupling) --
+                # the "halo exchange delivered a damaged value" scenario.
+                e_run = _faults.corrupt_entry("dist.halo", e_run)
+                d_run = jax.device_put(d_run, sliced)
+                e_run = jax.device_put(e_run, sliced)
                 if track is not None:
-                    track = jax.device_put(track, sharding)
+                    track = jax.device_put(
+                        track, NamedSharding(mesh, PartitionSpec()))
+            else:
+                sharding = _batch_sharding(Bb)
+                if sharding is not None:
+                    d_run = jax.device_put(d_run, sharding)
+                    e_run = jax.device_put(e_run, sharding)
+                    if track is not None:
+                        track = jax.device_put(track, sharding)
 
-            lam, rows, kprimes = _executor(
-                d_run, e_run, track, leaf=key.leaf, chunk=key.chunk,
-                niter=key.niter, use_zhat=key.use_zhat,
-                return_boundary=key.return_boundary,
+        with span("repro.plan.tree"):
+            knobs = dict(
+                leaf=key.leaf, chunk=key.chunk, niter=key.niter,
+                use_zhat=key.use_zhat, return_boundary=key.return_boundary,
                 tol_factor=key.tol_factor,
                 stream_threshold=key.stream_threshold,
                 deflate_budget=key.deflate_budget,
                 resident_threshold=key.resident_threshold, fused=key.fused)
+            if key.shards > 1:
+                lam, rows, kprimes = _executor_sharded(
+                    d_run, e_run, track, mesh=mesh, shards=key.shards,
+                    compress_halo=key.compress_halo, **knobs)
+            else:
+                lam, rows, kprimes = _executor(d_run, e_run, track,
+                                               **knobs)
         _br.SOLVE_COUNTER.increment()
         # Chaos-harness hook: NaN-poisons configured eigenvalue rows ("the
         # device returned garbage") so tests can drive the degradation

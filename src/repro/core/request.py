@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import guard as _guard
+from repro.core.instrument import span
 from repro.runtime import faults as _faults
 
 KINDS = ("full", "batch", "range", "slq", "edges")
@@ -485,54 +486,58 @@ def _finalize_lanes(routed: RoutedRequest, lam, blo, bhi, *,
             bad |= ~np.isfinite(np.asarray(blo)).all(axis=1, keepdims=True)
             bad |= ~np.isfinite(np.asarray(bhi)).all(axis=1, keepdims=True)
         if bad.any():
-            lam_h = lam_h.copy()
-            blo_h = np.asarray(blo).copy() if rows else None
-            bhi_h = np.asarray(bhi).copy() if rows else None
-            at = stage
-            if mixed or rows:
-                done = _resolve_native_rows(routed, bad.any(axis=1),
-                                            lam_h, blo_h, bhi_h)
-                if done.any():
-                    record(stage, "native", int(bad[done].sum()))
-                    at = "native"
-                bad = ~np.isfinite(lam_h)
-                if rows:
-                    bad |= ~np.isfinite(blo_h).all(axis=1, keepdims=True)
-                    bad |= ~np.isfinite(bhi_h).all(axis=1, keepdims=True)
-            if bad.any():
-                if rows:
-                    raise _guard.CertificationError(
-                        f"degradation ladder exhausted: {int(bad.sum())} "
-                        f"non-finite output lanes remain and the request "
-                        f"owes boundary rows, which bisection cannot "
-                        f"produce")
-                record(at, "bisect", int(bad.sum()))
-                _bisect_lanes(routed, lam_h, bad)
-                if cert_h is not None:
-                    cert_h[bad] = True   # count-verified by construction
-                still = ~np.isfinite(lam_h)
-                if still.any():
-                    raise _guard.CertificationError(
-                        f"degradation ladder exhausted: {int(still.sum())} "
-                        f"lanes non-finite even after Sturm bisection")
-            # Re-certify lanes repaired by a native re-solve (bisected
-            # lanes are already accounted above).
-            if cert_h is not None and not cert_h.all():
-                from repro.core import bisect as _bis
-                unchecked = (~cert_h).any(axis=1)
-                for b in np.nonzero(unchecked)[0]:
-                    c = _bis.certify_spectrum(
-                        routed.d[int(b)], routed.e[int(b)], lam_h[b],
-                        tol=getattr(routed.route, "refine_tol", 0.0)
-                        or None or _bis.DEFAULT_REFINE_TOL)
-                    cert_h[b] = np.asarray(c.certified)
-            lam, blo, bhi = lam_h, blo_h, bhi_h
+            with span("repro.request.ladder", lanes=int(bad.sum())):
+                lam_h = lam_h.copy()
+                blo_h = np.asarray(blo).copy() if rows else None
+                bhi_h = np.asarray(bhi).copy() if rows else None
+                at = stage
+                if mixed or rows:
+                    done = _resolve_native_rows(routed, bad.any(axis=1),
+                                                lam_h, blo_h, bhi_h)
+                    if done.any():
+                        record(stage, "native", int(bad[done].sum()))
+                        at = "native"
+                    bad = ~np.isfinite(lam_h)
+                    if rows:
+                        bad |= ~np.isfinite(blo_h).all(axis=1, keepdims=True)
+                        bad |= ~np.isfinite(bhi_h).all(axis=1, keepdims=True)
+                if bad.any():
+                    if rows:
+                        raise _guard.CertificationError(
+                            f"degradation ladder exhausted: {int(bad.sum())} "
+                            f"non-finite output lanes remain and the request "
+                            f"owes boundary rows, which bisection cannot "
+                            f"produce")
+                    record(at, "bisect", int(bad.sum()))
+                    _bisect_lanes(routed, lam_h, bad)
+                    if cert_h is not None:
+                        cert_h[bad] = True   # count-verified by construction
+                    still = ~np.isfinite(lam_h)
+                    if still.any():
+                        raise _guard.CertificationError(
+                            f"degradation ladder exhausted: "
+                            f"{int(still.sum())} lanes non-finite even "
+                            f"after Sturm bisection")
+                # Re-certify lanes repaired by a native re-solve (bisected
+                # lanes are already accounted above).
+                if cert_h is not None and not cert_h.all():
+                    from repro.core import bisect as _bis
+                    unchecked = (~cert_h).any(axis=1)
+                    for b in np.nonzero(unchecked)[0]:
+                        c = _bis.certify_spectrum(
+                            routed.d[int(b)], routed.e[int(b)], lam_h[b],
+                            tol=getattr(routed.route, "refine_tol", 0.0)
+                            or None or _bis.DEFAULT_REFINE_TOL)
+                        cert_h[b] = np.asarray(c.certified)
+                lam, blo, bhi = lam_h, blo_h, bhi_h
 
     if cert_h is not None and not cert_h.all():
         miss = ~cert_h
-        lam_h = np.asarray(lam).copy()
-        record(stage, "bisect", int(miss.sum()))
-        _bisect_lanes(routed, lam_h, miss)
+        lanes = int(miss.sum())
+        with span("repro.request.ladder", lanes=lanes):
+            lam_h = np.asarray(lam).copy()
+            record(stage, "bisect", lanes)
+            _bisect_lanes(routed, lam_h, miss)
         lam = lam_h
 
     if routed.scale != 1.0:
@@ -580,8 +585,17 @@ def execute_request(req: SolveRequest | RoutedRequest) -> SolveResult:
     This is the single launch path: the sync API wraps it, and the
     serving engine's flush is the same plan execution over a coalesced
     batch -- which is why service results are bit-for-bit the sync ones.
+    Recorded, routing included, as the span ``repro.request``.
     """
-    routed = route_request(req) if isinstance(req, SolveRequest) else req
+    fresh = isinstance(req, SolveRequest)
+    with span("repro.request",
+              kind=req.kind if fresh else req.request.kind) as s:
+        routed = route_request(req) if fresh else req
+        s.set_metadata(batch=routed.batch, n=routed.n)
+        return _execute_routed(routed)
+
+
+def _execute_routed(routed: RoutedRequest) -> SolveResult:
     req = routed.request
     single = routed.single
 

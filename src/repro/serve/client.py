@@ -23,6 +23,7 @@ from __future__ import annotations
 from concurrent.futures import Future
 
 from repro.core import plan as _plan
+from repro.core.instrument import span
 from repro.core.request import SolveRequest, SolveResult
 from repro.serve.engine import ServeEngine
 from repro.serve.metrics import ServeMetrics
@@ -67,10 +68,11 @@ class EigensolverClient:
 
     def solve_async(self, d, e, method: str = "br",
                     return_boundary: bool = False, **knobs) -> Future:
-        return self.submit(SolveRequest(
-            d=d, e=e, kind="full", method=method,
-            return_boundary=return_boundary, **_robust_kw(knobs),
-            knobs=knobs))
+        with span("repro.client.submit", problems=1):
+            return self.submit(SolveRequest(
+                d=d, e=e, kind="full", method=method,
+                return_boundary=return_boundary, **_robust_kw(knobs),
+                knobs=knobs))
 
     def solve(self, d, e, method: str = "br", **knobs):
         """All eigenvalues of one problem -- the service's sync mirror of
@@ -80,10 +82,11 @@ class EigensolverClient:
 
     def solve_batch_async(self, d, e, method: str = "br",
                           return_boundary: bool = False, **knobs) -> Future:
-        return self.submit(SolveRequest(
-            d=d, e=e, kind="batch", method=method,
-            return_boundary=return_boundary, **_robust_kw(knobs),
-            knobs=knobs))
+        with span("repro.client.submit", problems=len(d)):
+            return self.submit(SolveRequest(
+                d=d, e=e, kind="batch", method=method,
+                return_boundary=return_boundary, **_robust_kw(knobs),
+                knobs=knobs))
 
     def solve_batch(self, d, e, method: str = "br",
                     return_boundary: bool = False, **knobs) -> SolveResult:

@@ -3,13 +3,22 @@ launches and demuxes results back onto request futures.
 
 One daemon thread owns the device:
 
-    next_flush -> stage (host pack + pad) -> launch (async dispatch)
+    next_flush -> stage (host pack + pad) -> launch (dispatch)
                -> [stage/launch the NEXT flush]  -> finish (wait + demux)
 
 Staging and launching of flush i+1 overlap the device execution of flush
-i (double buffering): JAX dispatch is asynchronous, so ``_launch``
-returns as soon as the work is enqueued and ``_finish`` blocks on the
-previous flush's arrays only after the next one is already in flight.
+i (double buffering) only where ``_launch`` returns before the device is
+done: JAX dispatch is asynchronous, and ``_finish`` blocks on the
+previous flush's arrays only after the next one is in flight.  A
+mixed-precision flush (the default float64 route on a TPU) does not
+overlap.  Its launch runs the host-driven certify/polish
+(``bisect.refine_clusters``), which reads every polish launch's outputs
+on the host, so ``_launch`` returns only after the flush's device work.
+The profiler trace shows it: flush i's ``repro.polish.*`` spans lie
+inside its ``repro.serve.launch``, and flush i+1's ``repro.serve.stage``
+starts after them.  The flushes of a burst then run one after another,
+and each waits in the queue behind the launches before it.  Each step
+above is a span (``repro.serve.<step>``) sharing the flush's index.
 
 Mixed-n solve flushes are host-padded to the group's common padded width
 with ``_host_pad`` -- a numpy mirror of ``br_dc._pad_problem``'s
@@ -43,6 +52,7 @@ import numpy as np
 from repro.core import guard as _guard
 from repro.core import plan as _plan
 from repro.core import tune as _tune
+from repro.core.instrument import span
 from repro.core.request import SolveResult, _finalize_lanes, execute_request
 from repro.runtime import StragglerMonitor, Watchdog, retry_transient
 from repro.runtime import faults as _faults
@@ -109,11 +119,13 @@ class _Flush:
     """One staged flush: the launch inputs plus everything needed to
     demux device outputs back onto the member requests.  ``cert`` holds
     the flush-wide certificate mask (one batched Sturm sweep over the
-    padded flush) when the route carries ``certify=True``."""
+    padded flush) when the route carries ``certify=True``.  ``seq`` is
+    the flush's index in its spans; ``queue_wait_us`` is the time from
+    its oldest member's submit to its assembly."""
     __slots__ = ("batch", "route", "label", "result", "error", "t_launch",
-                 "cert")
+                 "cert", "seq", "problems", "queue_wait_us")
 
-    def __init__(self, batch, route, label):
+    def __init__(self, batch, route, label, seq, queue_wait_us):
         self.batch = batch
         self.route = route
         self.label = label
@@ -121,6 +133,9 @@ class _Flush:
         self.error: BaseException | None = None
         self.t_launch = 0.0
         self.cert = None
+        self.seq = seq
+        self.problems = sum(p.problems for p in batch)
+        self.queue_wait_us = queue_wait_us
 
 
 class ServeEngine:
@@ -138,6 +153,7 @@ class ServeEngine:
         self._stragglers: dict[str, StragglerMonitor] = {}
         self._thread: threading.Thread | None = None
         self._flush_index = 0
+        self._flush_seq = 0
         self._last_beat = 0.0
         self._beat_warned = False
 
@@ -189,7 +205,9 @@ class ServeEngine:
         # finished the moment no follow-up work is due); short waits
         # otherwise to notice close/drain quickly.
         timeout = 0.0 if inflight is not None else 0.05
-        batch = self.scheduler.next_flush(timeout=timeout)
+        with span("repro.serve.next_flush"):
+            batch = self.scheduler.next_flush(timeout=timeout)
+        assembled = time.monotonic()
         if batch is not None:
             # Flush assembly is the first point the engine owns the
             # requests: fail the ones whose deadline_ms budget ran out
@@ -207,7 +225,7 @@ class ServeEngine:
             # staging cost.
             self._finish_safely(inflight)
             inflight = None
-        flush = self._stage_and_launch(batch)
+        flush = self._stage_and_launch(batch, assembled)
         if inflight is not None:
             self._finish_safely(inflight)
         return flush
@@ -251,14 +269,20 @@ class ServeEngine:
 
     # ------------------------------------------------------------- stages
 
-    def _stage_and_launch(self, batch) -> _Flush:
-        """Stage + dispatch one flush; JAX dispatch is async so this
-        returns while the device still computes.  Errors (including any
-        raised at dispatch) are handled in _finish, whose relaunch path
-        owns the transient-retry budget -- execution faults only surface
-        at block_until_ready there, so that is where retrying belongs."""
+    def _stage_and_launch(self, batch, assembled: float) -> _Flush:
+        """Stage + dispatch one flush assembled (``time.monotonic()``) at
+        ``assembled``; JAX dispatch is async so this returns while the
+        device still computes -- unless the route's host-driven polish
+        blocks on it.  Errors (including any raised at dispatch) are
+        handled in _finish, whose relaunch path owns the transient-retry
+        budget -- execution faults only surface at block_until_ready
+        there, so that is where retrying belongs."""
         route = batch[0].routed.route
-        flush = _Flush(batch, route, bucket_label(route))
+        oldest = min(p.submit_t for p in batch)
+        flush = _Flush(batch, route, bucket_label(route),
+                       seq=self._flush_seq,
+                       queue_wait_us=(assembled - oldest) * 1e6)
+        self._flush_seq += 1
         flush.t_launch = time.perf_counter()
         try:
             # Chaos site "serve.stage": a delay here stalls staging (the
@@ -300,28 +324,36 @@ class ServeEngine:
         # count-driven schedule can fail the first dispatch and let the
         # transient-retry relaunch succeed (or keep failing to force the
         # per-request fallback).
-        _faults.inject("serve.launch")
-        route = flush.route
-        if isinstance(route, _plan.PlanKey):
-            return self._launch_solve(flush)
-        if isinstance(route, _plan.RangePlanKey):
-            return self._launch_range(flush)
-        # Direct (uncoalescable) request: the sync path, one launch.
-        return execute_request(flush.batch[0].routed)
+        with span("repro.serve.launch", flush=flush.seq):
+            _faults.inject("serve.launch")
+            route = flush.route
+            if isinstance(route, _plan.PlanKey):
+                return self._launch_solve(flush)
+            if isinstance(route, _plan.RangePlanKey):
+                return self._launch_range(flush)
+            # Direct (uncoalescable) request: the sync path, one launch.
+            return execute_request(flush.batch[0].routed)
+
+    def _stage_span(self, flush: _Flush):
+        """The span of a flush's host packing and padding."""
+        return span("repro.serve.stage", flush=flush.seq,
+                    problems=flush.problems,
+                    queue_wait_us=flush.queue_wait_us)
 
     def _launch_solve(self, flush: _Flush):
         route = flush.route
         N = route.padded_n
-        ds, es, orig_n = [], [], []
-        for p in flush.batch:
-            d = np.asarray(p.routed.d)
-            e = np.asarray(p.routed.e)
-            d, e = _host_pad(d, e, N)
-            ds.append(d)
-            es.append(e)
-            orig_n.extend([p.routed.n] * p.routed.batch)
-        d_all = np.concatenate(ds, axis=0)
-        e_all = np.concatenate(es, axis=0)
+        with self._stage_span(flush):
+            ds, es, orig_n = [], [], []
+            for p in flush.batch:
+                d = np.asarray(p.routed.d)
+                e = np.asarray(p.routed.e)
+                d, e = _host_pad(d, e, N)
+                ds.append(d)
+                es.append(e)
+                orig_n.extend([p.routed.n] * p.routed.batch)
+            d_all = np.concatenate(ds, axis=0)
+            e_all = np.concatenate(es, axis=0)
         plan = _plan.plan_for_route(route, d_all.shape[0])
         res = plan.execute(d_all, e_all,
                            orig_n=np.asarray(orig_n, np.int32))
@@ -343,18 +375,20 @@ class ServeEngine:
         return res
 
     def _launch_range(self, flush: _Flush):
-        d_all = np.concatenate([np.asarray(p.routed.d)
-                                for p in flush.batch], axis=0)
-        e_all = np.concatenate([np.asarray(p.routed.e)
-                                for p in flush.batch], axis=0)
-        # routed.il is a scalar window start for plain range requests and
-        # a per-problem (batch,) array for kind="edges" (duplicated rows,
-        # bottom-k + top-k windows); broadcast_to handles both, so edges
-        # probes and ordinary sliced traffic mix freely in one flush.
-        il = np.concatenate([np.broadcast_to(np.asarray(p.routed.il),
-                                             (p.routed.batch,))
-                             for p in flush.batch])
-        k = max(p.routed.k for p in flush.batch)
+        with self._stage_span(flush):
+            d_all = np.concatenate([np.asarray(p.routed.d)
+                                    for p in flush.batch], axis=0)
+            e_all = np.concatenate([np.asarray(p.routed.e)
+                                    for p in flush.batch], axis=0)
+            # routed.il is a scalar window start for plain range requests
+            # and a per-problem (batch,) array for kind="edges" (duplicated
+            # rows, bottom-k + top-k windows); broadcast_to handles both,
+            # so edges probes and ordinary sliced traffic mix freely in
+            # one flush.
+            il = np.concatenate([np.broadcast_to(np.asarray(p.routed.il),
+                                                 (p.routed.batch,))
+                                 for p in flush.batch])
+            k = max(p.routed.k for p in flush.batch)
         plan = _plan.range_plan_for_route(flush.route, d_all.shape[0])
         return plan.execute(d_all, e_all, il, k)
 
@@ -363,8 +397,9 @@ class ServeEngine:
     def _finish(self, flush: _Flush) -> None:
         if flush.error is None:
             try:
-                jax.block_until_ready(
-                    getattr(flush.result, "eigenvalues", flush.result))
+                with span("repro.serve.wait", flush=flush.seq):
+                    jax.block_until_ready(
+                        getattr(flush.result, "eigenvalues", flush.result))
             except Exception as exc:
                 flush.error = exc
         if (flush.error is not None and self.config.retries > 0
@@ -393,14 +428,15 @@ class ServeEngine:
             return
         duration = time.perf_counter() - flush.t_launch
         try:
-            self._demux(flush)
+            with span("repro.serve.demux", flush=flush.seq,
+                      requests=len(flush.batch)):
+                self._demux(flush)
         except Exception as exc:
             flush.error = exc
             self._fallback(flush)
             return
-        problems = sum(p.problems for p in flush.batch)
+        problems = flush.problems
         self.metrics.record_flush(flush.label, len(flush.batch), problems,
-                                  duration,
                                   tuned=bool(_tune.serve_knobs(flush.label)))
         now = time.monotonic()
         for p in flush.batch:

@@ -36,7 +36,7 @@ class _Bucket:
     __slots__ = ("requests", "problems", "flushes", "flushed_problems",
                  "tuned_flushes", "errors", "fallbacks", "retries",
                  "degradations", "degraded_lanes", "deadline_expired",
-                 "latency", "flush_time")
+                 "latency")
 
     def __init__(self):
         self.requests = 0          # submitted requests
@@ -51,7 +51,6 @@ class _Bucket:
         self.degraded_lanes = 0    # eigenvalue lanes recomputed by it
         self.deadline_expired = 0  # requests failed with DeadlineExceeded
         self.latency = LatencyRecorder()     # per-request submit->demux, s
-        self.flush_time = LatencyRecorder()  # per-flush device wall, s
 
 
 class ServeMetrics:
@@ -76,14 +75,13 @@ class ServeMetrics:
             b.problems += problems
 
     def record_flush(self, label: str, requests: int, problems: int,
-                     duration_s: float, tuned: bool = False) -> None:
+                     tuned: bool = False) -> None:
         b = self._bucket(label)
         with self._lock:
             b.flushes += 1
             b.flushed_problems += problems
             if tuned:
                 b.tuned_flushes += 1
-        b.flush_time.record(duration_s)
 
     def record_latency(self, label: str, seconds: float) -> None:
         self._bucket(label).latency.record(seconds)
@@ -144,6 +142,5 @@ class ServeMetrics:
                 }
             row["latency_p50_ms"] = b.latency.percentile(50) * 1e3
             row["latency_p99_ms"] = b.latency.percentile(99) * 1e3
-            row["flush_p50_ms"] = b.flush_time.percentile(50) * 1e3
             out["buckets"][label] = row
         return out
